@@ -96,7 +96,7 @@ def cg_native(mesh, axis, b, iters: int):
             return 2 * x - xm[:-2] - xm[2:]
 
         def dot(a, c):
-            return jax.lax.psum(jnp.vdot(a, c), axis)
+            return jax.lax.psum(jnp.sum(a * c), axis)
 
         x = jnp.zeros_like(b)
         r = b - matvec(x)

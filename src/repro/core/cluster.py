@@ -10,12 +10,15 @@ serialize→host→deserialize pipe the paper benchmarks against.
 """
 from __future__ import annotations
 
+import os
 import pickle
 import threading
+from pathlib import Path
 from typing import Optional
 
 import jax
 import numpy as np
+from jax.experimental.compilation_cache import compilation_cache
 
 from repro.core import comm as comm_mod
 from repro.core import compat
@@ -29,7 +32,12 @@ from repro.core.native import get_app, load_library
 from repro.core.partition import Block, block_aval, concat_blocks, from_host, place_block
 from repro.core.properties import IProperties
 from repro.core.textlambda import ISource
-from repro.kernels.registry import KernelRegistry
+from repro.kernels.registry import DEFAULT_BLOCKS, KernelRegistry
+
+
+#: persistent compile cache used when JAX_COMPILATION_CACHE_DIR is unset:
+#: a fixed path in the checkout (the path is part of the cache key)
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 class Ignis:
@@ -39,6 +47,15 @@ class Ignis:
 
     @classmethod
     def start(cls):
+        """Start the framework. Compiled programs persist across processes:
+        in ``JAX_COMPILATION_CACHE_DIR`` where it is set (jax reads it
+        itself), else in ``COMPILE_CACHE_DIR``."""
+        if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+            path = str(COMPILE_CACHE_DIR)
+            if jax.config.jax_compilation_cache_dir != path:
+                jax.config.update("jax_compilation_cache_dir", path)
+                # re-open the cache at the new path if a compile opened it
+                compilation_cache.reset_cache()
         cls._started = True
 
     @classmethod
@@ -134,7 +151,7 @@ class IWorker:
             headroom=cluster.props.get_float("ignis.shuffle.memory.headroom", 1.25),
             kernels=KernelRegistry(
                 mode=cluster.props.get("ignis.kernels", "auto"),
-                blocks=cluster.props.get("ignis.kernels.blocks", "128,256,512"),
+                blocks=cluster.props.get("ignis.kernels.blocks", DEFAULT_BLOCKS),
                 tune_cache_size=cluster.props.get_int(
                     "ignis.kernels.tune.cache.size", 512),
             ),

@@ -1,81 +1,45 @@
-"""Version-portable wrappers for jax APIs that moved between releases.
+"""The jax mesh and shard_map surface, in one place.
 
-The runtime targets the newest jax surface (``jax.shard_map``,
-``jax.make_mesh(..., axis_types=...)``) but must also run on older
-installs where ``shard_map`` still lives in ``jax.experimental`` (with
-``check_rep`` instead of ``check_vma``) and meshes carry no axis types.
-Everything in the repo goes through these two helpers instead of calling
-jax directly, so the version split lives in exactly one file.
+Everything in the repo builds meshes and SPMD programs through these
+helpers, so the axis types and the replication-check setting are chosen
+once: every mesh axis is ``Auto``, and ``shard_map`` runs with
+``check_vma=False``.
 """
 from __future__ import annotations
 
 import jax
 
-_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
+
+def _auto(axis_names):
+    return (jax.sharding.AxisType.Auto,) * len(axis_names)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the install supports them."""
-    if _HAS_AXIS_TYPE:
-        kinds = (jax.sharding.AxisType.Auto,) * len(axis_names)
-        return jax.make_mesh(axis_shapes, axis_names, axis_types=kinds)
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=_auto(axis_names))
 
 
 def make_mesh_of(devices, axis_names):
     """A Mesh over an EXPLICIT device array — the communicator-group path
     (``IContext.split``/``group``): sub-meshes must pin their device subset,
     which ``jax.make_mesh`` (auto device selection) cannot express."""
-    if _HAS_AXIS_TYPE:
-        kinds = (jax.sharding.AxisType.Auto,) * len(axis_names)
-        try:
-            return jax.sharding.Mesh(devices, axis_names, axis_types=kinds)
-        except TypeError:  # jax window with AxisType but no Mesh kwarg
-            pass
-    return jax.sharding.Mesh(devices, axis_names)
+    return jax.sharding.Mesh(devices, axis_names, axis_types=_auto(axis_names))
 
 
 def get_ambient_mesh():
-    """The mesh installed by ``set_mesh`` (or None): the abstract mesh on new
-    jax, the thread-resources physical mesh on old."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax._src import mesh as _mesh_lib
-
-    mesh = _mesh_lib.thread_resources.env.physical_mesh
-    return None if mesh.empty else mesh
+    """The abstract mesh installed by ``set_mesh`` (empty when none is)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh. On older jax
-    the Mesh object is itself the context manager (thread resources)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager installing ``mesh`` as the ambient mesh."""
+    return jax.set_mesh(mesh)
 
 
 def shard_map(f, mesh=None, in_specs=None, out_specs=None):
     """``jax.shard_map`` with replication checking off (our collectives use
-    unreduced intermediates that the checker rejects on every jax version)."""
-    if _NEW_SHARD_MAP:
-        kw = {} if mesh is None else {"mesh": mesh}
-        try:
-            return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
-                                 check_vma=False, **kw)
-        except TypeError:  # jax window with top-level shard_map but check_rep
-            return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
-                                 check_rep=False, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    if mesh is None:  # old jax cannot infer the mesh from context
-        from jax._src import mesh as _mesh_lib
-
-        mesh = _mesh_lib.thread_resources.env.physical_mesh
-        if mesh.empty:
-            raise RuntimeError(
-                "shard_map without an explicit mesh needs jax>=0.5 or an "
-                "enclosing `with mesh:` scope"
-            )
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    unreduced intermediates that the checker rejects). Without ``mesh`` the
+    ambient mesh of ``set_mesh`` is used."""
+    kw = {} if mesh is None else {"mesh": mesh}
+    return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False, **kw)

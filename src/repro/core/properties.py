@@ -162,8 +162,9 @@ register("ignis.kernels", "str", "auto",
          "Pallas kernel tier mode: auto picks compiled kernels where the "
          "backend supports them; interpret forces CI conformance mode.",
          choices=("auto", "on", "interpret", "off"))
-register("ignis.kernels.blocks", "str", "128,256,512",
-         "Autotune sweep block-size candidates (comma separated).")
+register("ignis.kernels.blocks", "str", "8192,32768,131072",
+         "Autotune sweep candidates: rows per kernel grid step, rounded up "
+         "to whole (8, 128) tiles (comma separated).")
 register("ignis.kernels.tune.cache.size", "int", "512",
          "Autotune memo LRU entries.")
 

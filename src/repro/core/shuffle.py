@@ -297,6 +297,46 @@ def segment_heads(keys, valid):
     return valid & (first | (keys != prev) | ~jnp.concatenate([valid[:1], valid[:-1]]))
 
 
+#: rows per step of ``chunked_scan``
+SCAN_CHUNK = 1 << 14
+
+
+def chunked_scan(comb, elems, chunk: int = SCAN_CHUNK):
+    """Inclusive ``associative_scan`` along axis 0 of a pytree of arrays.
+
+    XLA:TPU's compile time and memory grow with the length of a scan (a
+    2^20-row ``associative_scan`` or ``cummin`` takes minutes to compile
+    for v5e, and a 2^27-row stage crashed the compiler). So rows longer
+    than ``chunk`` are scanned one chunk per ``lax.scan`` step, with the
+    running total carried into the next chunk: one chunk-sized program at
+    any length. ``comb`` must broadcast a ``(1, ...)`` carry against
+    ``(chunk, ...)``. Exact for associative-exact data (ints, min, max);
+    float sums associate differently from a plain scan.
+    """
+    n = jax.tree.leaves(elems)[0].shape[0]
+    if n <= chunk:
+        return jax.lax.associative_scan(comb, elems)
+    R = -(-n // chunk)
+
+    def chunks(x):  # (n, ...) → (R, chunk, ...); the padding trails every row
+        x = jnp.pad(x, [(0, R * chunk - n)] + [(0, 0)] * (x.ndim - 1))
+        return x.reshape((R, chunk) + x.shape[1:])
+
+    xs = jax.tree.map(chunks, elems)
+    first = jax.lax.associative_scan(comb, jax.tree.map(lambda x: x[0], xs))
+
+    def step(carry, x):
+        s = comb(jax.tree.map(lambda c: c[None], carry),
+                 jax.lax.associative_scan(comb, x))
+        return jax.tree.map(lambda y: y[-1], s), s
+
+    _, rest = jax.lax.scan(step, jax.tree.map(lambda y: y[-1], first),
+                           jax.tree.map(lambda x: x[1:], xs))
+    return jax.tree.map(
+        lambda a, b: jnp.concatenate([a, b.reshape((-1,) + b.shape[2:])])[:n],
+        first, rest)
+
+
 def segmented_reduce(keys, valid, values, fn, identity):
     """Reduce consecutive equal-key runs (keys must be sorted, invalid at
     arbitrary positions). Returns (head_mask, reduced_values_at_heads).
@@ -320,17 +360,17 @@ def segmented_reduce(keys, valid, values, fn, identity):
         vb, hb = b
         merged = fn(va, vb)
         v = jax.tree.map(
-            lambda m, y: jnp.where(hb.reshape((-1,) + (1,) * (y.ndim - 1)), y, m),
+            lambda m, y: jnp.where(hb.reshape(hb.shape + (1,) * (y.ndim - hb.ndim)), y, m),
             merged,
             vb,
         )
         return (v, ha | hb)
 
-    scanned, _ = jax.lax.associative_scan(comb, (vals, heads_ext))
+    scanned, _ = chunked_scan(comb, (vals, heads_ext))
     # last row of each segment = (next head_ext) - 1
     idx = jnp.arange(n)
     head_pos = jnp.where(heads_ext, idx, n)
-    suff_min = jax.lax.cummin(head_pos[::-1])[::-1]
+    suff_min = chunked_scan(jnp.minimum, head_pos[::-1])[::-1]
     nxt = jnp.concatenate([suff_min[1:], jnp.full((1,), n)])
     last_pos = jnp.clip(jnp.where(nxt >= n, n - 1, nxt - 1), 0, n - 1)
     out = jax.tree.map(lambda s: s[last_pos], scanned)

@@ -351,7 +351,7 @@ class ShuffleManager:
 
         leaf = jax.tree_util.tree_leaves(b.data["value"])[0]
         D = () if leaf.ndim == 1 else leaf.shape[1:]
-        n = b.capacity
+        n = b.capacity // max(self.p, 1)  # the kernel runs on one shard
         key = ("segment_reduce", op, str(leaf.dtype), D, n,
                sel.interpret, jax.default_backend())
 
@@ -388,8 +388,8 @@ class ShuffleManager:
             return None, None
         try:
             blk = self._tune_route(n_local, sel)
-        except Exception:
-            self.kernels.demote()
+        except Exception as e:
+            self.kernels.degrade(sel, e)
             return None, None
         self._note(sig, f"{sel.describe()} block={blk}")
         return sel, blk
@@ -447,8 +447,8 @@ class ShuffleManager:
         if sel is not None:
             try:
                 blk = self._tune_reduce(b, op, sel)
-            except Exception:
-                self.kernels.demote()
+            except Exception as e:
+                self.kernels.degrade(sel, e)
                 sel = None
         if sel is not None:
             self._note(sig, f"{sel.describe()} op={op} block={blk}")

@@ -1,4 +1,4 @@
-"""Public MoE routing wrapper: padding + CPU auto-interpret."""
+"""Public routing wrappers: CPU auto-interpret (and padding for moe_route)."""
 from __future__ import annotations
 
 import jax
@@ -11,7 +11,7 @@ def _should_interpret():
     return jax.default_backend() != "tpu"
 
 
-def bucket_route(dest, p: int, capacity: int, block: int = 512, interpret=None):
+def bucket_route(dest, p: int, capacity: int, block: int = 8192, interpret=None):
     """Shuffle-exchange routing (route.py): capacity ordinals in row order.
 
     dest: (N,) int32 in [0, p). Returns (pos (N,) i32, keep (N,) bool,
@@ -24,15 +24,8 @@ def bucket_route(dest, p: int, capacity: int, block: int = 512, interpret=None):
     if N == 0:
         return (jnp.zeros(0, jnp.int32), jnp.zeros(0, bool),
                 jnp.zeros(p, jnp.int32))
-    d = dest.astype(jnp.int32)
-    pad = (-N) % block if N > block else 0
-    if pad:
-        # the sentinel p one-hots to an all-zero row: padding neither
-        # claims ordinals nor inflates counts
-        d = jnp.concatenate([d, jnp.full((pad,), p, jnp.int32)])
-    pos, keep, counts = bucket_route_fwd(d, p=p, capacity=capacity,
-                                         block=block, interpret=interpret)
-    return pos[:N], keep[:N], counts
+    return bucket_route_fwd(dest, p=p, capacity=capacity, block=block,
+                            interpret=interpret)
 
 
 def moe_route(logits, k: int, capacity: int, block_t: int = 256, interpret=None):

@@ -3,10 +3,14 @@
 The MoE router's capacity-ordinal technique (moe_route.py) applied to the
 shuffle engine's exchange: rows are "tokens", destination executors are
 "experts", bucket capacity C is the expert capacity. Grid (n_blocks,)
-sequential over row tiles; a VMEM (1, p) scratch carries per-destination
-running counts, so ordinals are globally consistent in row order without
-an argsort. Per tile: one-hot cumsum for in-tile ordinals, a carried-count
-gather for the base.
+sequential over lane-dense ``(rows, 128)`` tiles of the destinations
+(ssd_scan/prefix.py ``lane_layout``). The per-destination running counts
+live in the ``(p, 1, 128)`` counts output, which stays resident in VMEM
+across the grid, so ordinals are globally consistent in row order without
+an argsort. Per tile and destination: a ``scan_tile`` prefix count of the
+rows routed there gives the in-tile ordinal, and the carried count gives
+the base. ``p`` is the executor count, so the loop over destinations is
+short.
 
 Ordinals are exact integers — for row r with destination b, ``pos`` is the
 number of earlier rows routed to b, which is precisely the rank a stable
@@ -22,55 +26,50 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.ssd_scan.prefix import LANES, lane_layout, scan_tile, tile_last, to_lanes
 
 
-def _kernel(d_ref, pos_ref, keep_ref, cnt_ref, counts, *, bt, p, capacity, n_blocks):
-    t = pl.program_id(0)
-
-    @pl.when(t == 0)
+def _kernel(d_ref, pos_ref, keep_ref, cnt_ref, *, p, capacity):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        counts[...] = jnp.zeros_like(counts)
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    d = d_ref[...]  # (bt,) int32 in [0, p); == p marks padding rows
-    oh = jax.nn.one_hot(d, p, dtype=jnp.int32)  # (bt, p); pad rows → all-zero
-    csum = jnp.cumsum(oh, axis=0)
-    local = ((csum - oh) * oh).sum(-1)  # exclusive in-tile ordinal
-    base = (oh * counts[...]).sum(-1)  # carried counts gathered per row
-    pos = base + local
+    d = d_ref[...]  # (rows, 128) int32 in [0, p); == p marks padding rows
+    pos = jnp.zeros_like(d)
+    for b in range(p):
+        hit = d == b
+        seen, _ = scan_tile(hit.astype(jnp.int32), None, jnp.add)
+        base = cnt_ref[b]  # (1, 128): rows routed to b by earlier tiles
+        pos = jnp.where(hit, base + seen - 1, pos)
+        cnt_ref[b] = base + tile_last(seen)
     pos_ref[...] = pos
-    keep_ref[...] = (pos < capacity) & (d < p)
-    counts[...] = counts[...] + csum[-1:]
-
-    @pl.when(t == n_blocks - 1)
-    def _fin():
-        cnt_ref[...] = counts[0]
+    keep_ref[...] = ((pos < capacity) & (d < p)).astype(jnp.int32)
 
 
-def bucket_route_fwd(dest, p: int, capacity: int, block: int = 512,
+def bucket_route_fwd(dest, p: int, capacity: int, block: int = 8192,
                      interpret: bool = False):
-    """dest: (N,) int32 in [0, p] (p = padding sentinel), N % block == 0
-    (the ops wrapper pads). Returns (pos (N,) i32, keep (N,) bool,
-    counts (p,) i32 — final per-destination demand)."""
+    """dest: (N,) int32 in [0, p). ``block`` is the number of rows per grid
+    step (whole (8, 128) tiles; see ``lane_layout``). Returns (pos (N,)
+    i32, keep (N,) bool, counts (p,) i32 — final per-destination demand)."""
     (N,) = dest.shape
-    bt = min(block, N)
-    n_blocks = N // bt
-    kern = functools.partial(_kernel, bt=bt, p=p, capacity=capacity,
-                             n_blocks=n_blocks)
-    return pl.pallas_call(
-        kern,
-        grid=(n_blocks,),
-        in_specs=[pl.BlockSpec((bt,), lambda i: (i,))],
-        out_specs=[
-            pl.BlockSpec((bt,), lambda i: (i,)),
-            pl.BlockSpec((bt,), lambda i: (i,)),
-            pl.BlockSpec((p,), lambda i: (0,)),
-        ],
+    rows, n_pad = lane_layout(N, block)
+    # the sentinel p matches no destination: padding neither claims
+    # ordinals nor inflates counts
+    d = to_lanes(dest.astype(jnp.int32), n_pad, p)
+    spec = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
+    pos, keep, counts = pl.pallas_call(
+        functools.partial(_kernel, p=p, capacity=capacity),
+        grid=(d.shape[0] // rows,),
+        in_specs=[spec],
+        out_specs=[spec, spec,
+                   pl.BlockSpec((p, 1, LANES), lambda i: (0, 0, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((N,), jnp.int32),
-            jax.ShapeDtypeStruct((N,), jnp.bool_),
-            jax.ShapeDtypeStruct((p,), jnp.int32),
+            jax.ShapeDtypeStruct(d.shape, jnp.int32),
+            jax.ShapeDtypeStruct(d.shape, jnp.int32),
+            jax.ShapeDtypeStruct((p, 1, LANES), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((1, p), jnp.int32)],
         interpret=interpret,
-    )(dest)
+    )(d)
+    return (pos.reshape(n_pad)[:N], keep.reshape(n_pad)[:N].astype(bool),
+            counts[:, 0, 0])

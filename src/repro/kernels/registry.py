@@ -13,9 +13,12 @@ available. This module decides, per wide node, which one runs:
   ``interpret=True`` otherwise); ``interpret`` forces interpret mode
   (the CI conformance path); ``off`` forces the fallback.
 * **Capability probe**: a tiny invocation per (kernel, interpret,
-  backend), cached; any failure degrades that kernel to the fallback
-  instead of erroring. The ``kernel.capability`` fault site fires on
-  every selection so chaos tests can force mid-job degradation.
+  backend), cached. An interpreted kernel that fails its probe degrades
+  to the fallback; a compiled one raises ``KernelUnavailable`` with the
+  compiler's message, and so does a failed autotune sweep of a compiled
+  kernel (``degrade``): a chip run never quietly leaves the kernel path
+  it claims to run. The ``kernel.capability`` fault site fires on every
+  selection so chaos tests can force mid-job degradation.
 * **Autotune memo**: best block size per (kernel, aval, op) key, found
   by a timed sweep over ``ignis.kernels.blocks`` candidates. The memo
   is an LRU with single-builder discipline (per-key in-flight Event,
@@ -40,14 +43,22 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.metrics import Counters
+from repro.kernels.ssd_scan.prefix import TILE
 
 #: dtypes the kernel tier computes natively (bool rides as i32)
 SUPPORTED_DTYPES = ("float32", "int32")
+
+#: autotune candidates: rows per grid step, whole (8, 128) tiles each
+DEFAULT_BLOCKS = "8192,32768,131072"
 
 
 def compiled_backend() -> bool:
     """True where pl.pallas_call lowers to a real Mosaic kernel."""
     return jax.default_backend() == "tpu"
+
+
+class KernelUnavailable(RuntimeError):
+    """A compiled kernel failed its probe or its autotune sweep."""
 
 
 @dataclass(frozen=True)
@@ -69,25 +80,25 @@ class Selection:
 def _probe_segment_reduce(interpret: bool):
     from repro.kernels.segment_reduce.segment_reduce import segment_reduce_fwd
 
-    v = jnp.zeros((8, 1), jnp.float32)
-    hb = jnp.ones((8,), bool)
+    v = jnp.zeros((TILE, 1), jnp.float32)
+    hb = jnp.ones((TILE,), bool)
     jax.block_until_ready(
-        segment_reduce_fwd(v, hb, op="sum", block=8, interpret=interpret))
+        segment_reduce_fwd(v, hb, op="sum", block=TILE, interpret=interpret))
 
 
 def _probe_prefix_scan(interpret: bool):
     from repro.kernels.ssd_scan.prefix import prefix_scan_fwd
 
-    x = jnp.zeros((8,), jnp.int32)
-    jax.block_until_ready(prefix_scan_fwd(x, op="min", block=8, interpret=interpret))
+    x = jnp.zeros((TILE,), jnp.int32)
+    jax.block_until_ready(prefix_scan_fwd(x, op="min", block=TILE, interpret=interpret))
 
 
 def _probe_bucket_route(interpret: bool):
     from repro.kernels.moe_route.route import bucket_route_fwd
 
-    d = jnp.zeros((8,), jnp.int32)
+    d = jnp.zeros((TILE,), jnp.int32)
     jax.block_until_ready(
-        bucket_route_fwd(d, p=2, capacity=4, block=8, interpret=interpret))
+        bucket_route_fwd(d, p=2, capacity=4, block=TILE, interpret=interpret))
 
 
 _PROBES: dict = {
@@ -150,7 +161,7 @@ class KernelRegistry:
 
     MODES = ("auto", "on", "off", "interpret")
 
-    def __init__(self, mode: str = "auto", blocks="128,256,512",
+    def __init__(self, mode: str = "auto", blocks=DEFAULT_BLOCKS,
                  tune_cache_size: int = 512):
         mode = str(mode).strip().lower()
         if mode not in self.MODES:
@@ -186,7 +197,11 @@ class KernelRegistry:
         try:
             _PROBES[kernel](interpret)
             ok = True
-        except Exception:
+        except Exception as e:
+            if not interpret:  # nothing cached: the next selection re-probes
+                raise KernelUnavailable(
+                    f"{kernel}: compiled probe failed on "
+                    f"{jax.default_backend()}: {e}") from e
             ok = False
         with self._lock:
             self._probe_cache[key] = ok
@@ -196,10 +211,11 @@ class KernelRegistry:
         """Resolve one kernel-eligible wide node. None → plain-JAX
         fallback (always available, bit-identical for exact ops).
 
-        A ``kernel.capability`` fault or a failed probe degrades to the
-        fallback rather than erroring — capability loss mid-job must not
-        kill the job (unlike ``kernel.stage``, which is a task fault the
-        scheduler retries via lineage).
+        A ``kernel.capability`` fault or a failed interpreted probe
+        degrades to the fallback rather than erroring — capability loss
+        mid-job must not kill the job (unlike ``kernel.stage``, which is a
+        task fault the scheduler retries via lineage). A failed compiled
+        probe raises ``KernelUnavailable``.
         """
         # deferred import: repro.core.shuffle_plan imports this module at
         # class-definition time, so a module-level core import would cycle
@@ -237,6 +253,16 @@ class KernelRegistry:
         with self._lock:
             self.stats["kernel_hits"] -= 1
             self.stats["kernel_fallbacks"] += 1
+
+    def degrade(self, sel: Selection, exc: Exception):
+        """A post-selection step (the autotune sweep) of ``sel`` failed.
+        Interpreted: ``demote`` and let the caller take the plain-JAX path.
+        Compiled: raise ``KernelUnavailable`` with the failure's message."""
+        if not sel.interpret:
+            raise KernelUnavailable(
+                f"{sel.kernel}: compiled autotune sweep failed on "
+                f"{jax.default_backend()}: {exc}") from exc
+        self.demote()
 
     # ------------------------------------------------------------------
     # autotune memo (single-builder, LRU — comm.py plan-cache discipline)

@@ -1,4 +1,4 @@
-"""Public segment_reduce wrappers: masking, padding, CPU auto-interpret.
+"""Public segment_reduce wrappers: masking, CPU auto-interpret.
 
 ``segment_reduce`` is the standalone inclusive-scan entry (kernel tests);
 ``segment_totals`` is the shuffle-stage ABI (docs/kernels.md): the drop-in
@@ -28,27 +28,20 @@ def _compute_dtype(dtype):
 
 
 def _scan(keys, valid, values, op, mask_value, block, interpret):
-    """Shared core: mask invalid rows to ``mask_value``, pad to a block
-    multiple with the op identity, run the segmented-scan kernel.
-    Returns (heads, scanned (N, D) in the compute dtype, squeeze)."""
+    """Shared core: mask invalid rows to ``mask_value``, run the
+    segmented-scan kernel. Returns (heads, scanned (N, D) in the compute
+    dtype, squeeze)."""
     squeeze = values.ndim == 1
     v = values[:, None] if squeeze else values
     ct = _compute_dtype(v.dtype)
     heads = heads_of(keys, valid)
     hb = heads | ~valid
     v = jnp.where(valid[:, None], v.astype(ct), jnp.asarray(mask_value, ct))
-
-    N = v.shape[0]
-    ident = op_identity(op, ct)
-    pad = (-N) % block if N > block else 0
-    if pad:
-        v = jnp.concatenate([v, jnp.full((pad, v.shape[1]), ident, v.dtype)])
-        hb = jnp.concatenate([hb, jnp.ones((pad,), bool)])
-    out = segment_reduce_fwd(v, hb, op=op, block=block, interpret=interpret)[:N]
+    out = segment_reduce_fwd(v, hb, op=op, block=block, interpret=interpret)
     return heads, out, squeeze
 
 
-def segment_reduce(keys, valid, values, op: str = "sum", block: int = 256,
+def segment_reduce(keys, valid, values, op: str = "sum", block: int = 8192,
                    interpret=None):
     """Inclusive segmented scan over sorted-key runs.
 
@@ -63,7 +56,7 @@ def segment_reduce(keys, valid, values, op: str = "sum", block: int = 256,
     return heads, (out[:, 0] if squeeze else out)
 
 
-def segment_totals(keys, valid, values, op: str, identity, block: int = 256,
+def segment_totals(keys, valid, values, op: str, identity, block: int = 8192,
                    interpret=None):
     """Shuffle-stage ABI: per-segment totals broadcast to every row.
 

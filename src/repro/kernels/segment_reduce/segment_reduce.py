@@ -1,10 +1,12 @@
 """Sorted segmented reduction — Pallas TPU kernel.
 
-Grid (n_blocks,) sequential over row tiles; scratch carries the running
-segment value across tiles. In-tile segmented inclusive scan is a
-Hillis–Steele log-depth sweep (static python loop of shifted selects —
-VPU-friendly, no HBM intermediates). Backs reduceByKey/groupBy of the
-dataflow layer (paper's TeraSort/K-Means path).
+Grid (D, n_blocks): one value column at a time, sequential over row tiles
+of that column; a ``(1, 128)`` VMEM tile carries the running segment value
+across tiles. Each column and the boundary flags are laid out lane-dense
+as ``(N/128, 128)`` (ssd_scan/prefix.py ``lane_layout``), and the in-tile
+segmented inclusive scan is prefix.py's ``scan_tile``: log-depth
+``pltpu.roll`` sweeps with iota masks, no HBM intermediates. Backs
+reduceByKey/groupBy of the dataflow layer (paper's TeraSort/K-Means path).
 
 Compute dtype follows the input (f32 floats, i32 ints — the ops wrapper
 normalizes): integer reductions are associative-exact, which is what lets
@@ -20,58 +22,44 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ssd_scan.prefix import op_identity
+from repro.kernels.ssd_scan.prefix import LANES, lane_layout, scan_tile, tile_last, to_lanes
 
 _FNS = {"sum": jnp.add, "max": jnp.maximum, "min": jnp.minimum}
 
 
-def _kernel(v_ref, h_ref, o_ref, carry, *, bq, n_blocks, op, ident):
+def _kernel(v_ref, h_ref, o_ref, carry, *, op):
     fn = _FNS[op]
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        carry[...] = jnp.full_like(carry, ident)
-
-    v = v_ref[...]  # (bq, D)
-    hb = h_ref[...]  # (bq,) bool: segment boundary (head-or-invalid)
-
-    # Hillis–Steele segmented inclusive scan
-    f = hb
-    off = 1
-    while off < bq:
-        v_sh = jnp.concatenate([jnp.full((off, v.shape[1]), ident, v.dtype), v[:-off]])
-        f_sh = jnp.concatenate([jnp.ones((off,), bool), f[:-off]])
-        v = jnp.where(f[:, None], v, fn(v, v_sh))
-        f = f | f_sh
-        off *= 2
-
-    # inject carry into the prefix that continues the previous tile's segment
-    seen = jnp.cumsum(hb.astype(jnp.int32)) > 0
-    v = jnp.where(seen[:, None], v, fn(v, carry[...]))
+    i = pl.program_id(1)
+    v, seen = scan_tile(v_ref[...], h_ref[...], fn)
+    # the prefix before the tile's first boundary continues the previous
+    # tile's segment
+    v = jnp.where((i > 0) & (seen == 0), fn(carry[...], v), v)
     o_ref[...] = v
-    carry[...] = v[-1:]
+    carry[...] = tile_last(v)
 
 
-def segment_reduce_fwd(values, boundaries, op: str = "sum", block: int = 256,
+def segment_reduce_fwd(values, boundaries, op: str = "sum", block: int = 8192,
                        interpret: bool = False):
     """values: (N, D) pre-masked on invalid rows; boundaries: (N,) bool =
-    head-or-invalid flags. N % block == 0 (ops.py pads with the op
-    identity). Returns the inclusive segmented scan (N, D), values.dtype."""
+    head-or-invalid flags. ``block`` is the number of rows per grid step
+    (whole (8, 128) tiles; see ``lane_layout``). Returns the inclusive
+    segmented scan (N, D), values.dtype."""
     N, D = values.shape
-    bq = min(block, N)
-    n_blocks = N // bq
-    ident = op_identity(op, values.dtype)
-    kern = functools.partial(_kernel, bq=bq, n_blocks=n_blocks, op=op, ident=ident)
-    return pl.pallas_call(
-        kern,
-        grid=(n_blocks,),
+    rows, n_pad = lane_layout(N, block)
+    # padding rows are segments of their own, past every real row
+    flags = to_lanes(boundaries.astype(jnp.int32), n_pad, 1)
+    cols = jax.vmap(lambda c: to_lanes(c, n_pad, 0))(values.T)  # (D, R, 128)
+    R = flags.shape[0]
+    out = pl.pallas_call(
+        functools.partial(_kernel, op=op),
+        grid=(D, R // rows),
         in_specs=[
-            pl.BlockSpec((bq, D), lambda i: (i, 0)),
-            pl.BlockSpec((bq,), lambda i: (i,)),
+            pl.BlockSpec((None, rows, LANES), lambda d, i: (d, i, 0)),
+            pl.BlockSpec((rows, LANES), lambda d, i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((bq, D), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, D), values.dtype),
-        scratch_shapes=[pltpu.VMEM((1, D), values.dtype)],
+        out_specs=pl.BlockSpec((None, rows, LANES), lambda d, i: (d, i, 0)),
+        out_shape=jax.ShapeDtypeStruct(cols.shape, values.dtype),
+        scratch_shapes=[pltpu.VMEM((1, LANES), values.dtype)],
         interpret=interpret,
-    )(values, boundaries)
+    )(cols, flags)
+    return out.reshape(D, n_pad)[:, :N].T

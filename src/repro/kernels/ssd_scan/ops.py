@@ -9,7 +9,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.ssd_scan.prefix import op_identity, prefix_scan_fwd
+from repro.kernels.ssd_scan.prefix import prefix_scan_fwd
 from repro.kernels.ssd_scan.ref import ssd_ref
 from repro.kernels.ssd_scan.ssd_scan import ssd_scan_fwd
 
@@ -18,7 +18,7 @@ def _should_interpret():
     return jax.default_backend() != "tpu"
 
 
-def prefix_scan(x, op: str = "sum", block: int = 512, interpret=None,
+def prefix_scan(x, op: str = "sum", block: int = 8192, interpret=None,
                 reverse: bool = False):
     """Inclusive prefix scan (sum/max/min) over a 1-D array.
 
@@ -34,11 +34,7 @@ def prefix_scan(x, op: str = "sum", block: int = 512, interpret=None,
     v = x.astype(jnp.int32) if squeeze_bool else x
     if reverse:
         v = v[::-1]
-    ident = op_identity(op, v.dtype)
-    pad = (-N) % block if N > block else 0
-    if pad:
-        v = jnp.concatenate([v, jnp.full((pad,), ident, v.dtype)])
-    out = prefix_scan_fwd(v, op=op, block=block, interpret=interpret)[:N]
+    out = prefix_scan_fwd(v, op=op, block=block, interpret=interpret)
     if reverse:
         out = out[::-1]
     return out.astype(bool) if squeeze_bool else out

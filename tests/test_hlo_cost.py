@@ -123,7 +123,7 @@ def test_wide_stage_collective_priced():
     half of stage pricing (DESIGN.md §13)."""
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from repro.core.compat import shard_map
 
     if len(jax.devices()) < 2:
         import pytest

@@ -27,6 +27,7 @@ import pytest
 
 from repro.core import ICluster, IProperties, IWorker
 from repro.core import faults
+from repro.core import shuffle as sh
 from repro.core.faults import FaultPlan
 from repro.core.shuffle import segmented_reduce
 from repro.kernels import registry as reg
@@ -140,6 +141,30 @@ def test_segment_totals_nonzero_identity_at_invalid_rows():
     _, t2 = segmented_reduce(keys, valid, vals, _FNS["sum"], ident)
     assert bits_equal(t1, t2)
     assert bool((t1[~valid] == 41).all())
+
+
+@pytest.mark.parametrize("n", [16, 17, 100])
+def test_chunked_scan_matches_associative_scan(n):
+    # the oracle scans long inputs chunk by chunk (XLA:TPU compiles one
+    # chunk-sized program); the carry across chunks must be exact
+    x = _data(n, "int32", seed=n)
+    assert bits_equal(sh.chunked_scan(jnp.minimum, x, chunk=16), jax.lax.cummin(x))
+
+    def comb(a, b):
+        return jnp.where(b[1][:, None], b[0], a[0] + b[0]), a[1] | b[1]
+
+    v = jnp.stack([x, -x, 2 * x], axis=1)
+    f = _data(n, "bool", seed=n + 1)
+    got = sh.chunked_scan(comb, (v, f), chunk=16)
+    ref = jax.lax.associative_scan(comb, (v, f))
+    assert all(bits_equal(a, b) for a, b in zip(got, ref))
+
+
+def test_segment_totals_matches_oracle_across_scan_chunks():
+    keys, valid, vals = _segments(sh.SCAN_CHUNK + 300, 500, 0.9, "int32")
+    h1, t1 = segment_totals(keys, valid, vals, "sum", jnp.int32(0), interpret=True)
+    h2, t2 = segmented_reduce(keys, valid, vals, _FNS["sum"], jnp.int32(0))
+    assert bits_equal(h1, h2) and bits_equal(t1, t2)
 
 
 # ---------------------------------------------------------------------------
@@ -524,3 +549,48 @@ def test_tuned_block_feeds_the_plan_key():
     assert rows[0] == rows[1]
     assert "block=64" in plans[0]
     assert "block=128" in plans[1]
+
+
+# ---------------------------------------------------------------------------
+# compiled backends fail loudly: no cached fallback behind a chip run
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("mode", ["auto", "on"])
+def test_compiled_probe_failure_raises(monkeypatch, mode):
+    def boom(interpret):
+        raise RuntimeError("Mosaic failed to compile TPU kernel: boom")
+
+    monkeypatch.setattr(reg, "compiled_backend", lambda: True)
+    monkeypatch.setitem(reg._PROBES, "segment_reduce", boom)
+    r = KernelRegistry(mode=mode)
+    with pytest.raises(reg.KernelUnavailable,
+                       match="segment_reduce.*Mosaic failed to compile"):
+        r.select("segment_reduce")
+    assert r.stats["kernel_fallbacks"] == 0
+    # nothing was cached: a repaired backend selects the kernel next time
+    monkeypatch.setitem(reg._PROBES, "segment_reduce", lambda interpret: None)
+    sel = r.select("segment_reduce")
+    assert sel is not None and not sel.interpret
+
+
+def test_compiled_tune_failure_raises_from_the_wide_stage(monkeypatch):
+    # the probes pass, but the sweep's compiled kernel cannot run on this
+    # host: the action must raise, naming the kernel, not fall back
+    monkeypatch.setattr(reg, "compiled_backend", lambda: True)
+    for name in list(reg._PROBES):
+        monkeypatch.setitem(reg._PROBES, name, lambda interpret: None)
+    w = _worker("auto", **{"ignis.kernels.blocks": "1024,2048"})
+    vals = np.arange(4096, dtype=np.int32)
+    df = (w.parallelize(vals).map(lambda x: {"key": x % 13, "value": x})
+          .reduce_by_key(lambda a, b: a + b, 0))
+    with pytest.raises(reg.KernelUnavailable, match="segment_reduce.*autotune"):
+        df.collect()
+    assert w.metrics("kernels")["kernel_fallbacks"] == 0
+
+
+def test_interpreted_tune_failure_still_degrades():
+    r = KernelRegistry(mode="interpret")
+    sel = r.select("bucket_route")
+    r.degrade(sel, RuntimeError("boom"))
+    assert r.stats["kernel_hits"] == 0 and r.stats["kernel_fallbacks"] == 1

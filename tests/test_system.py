@@ -159,3 +159,51 @@ def test_foreach(worker):
     worker.parallelize(np.arange(5, dtype=np.int32)).foreach(
         lambda r: seen.append(int(np.asarray(r))))
     assert sorted(seen) == [0, 1, 2, 3, 4]
+
+
+@pytest.fixture
+def cache_config():
+    """Restore jax's compile-cache path after a test moves it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    yield jax.config
+    jax.config.update("jax_compilation_cache_dir", was)
+    compilation_cache.reset_cache()
+
+
+def test_start_leaves_an_env_placed_compile_cache_alone(cache_config, monkeypatch,
+                                                        tmp_path):
+    # jax reads JAX_COMPILATION_CACHE_DIR itself; the repo must not override it
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    cache_config.update("jax_compilation_cache_dir", str(tmp_path))
+    Ignis.start()
+    assert cache_config.jax_compilation_cache_dir == str(tmp_path)
+
+
+def test_start_places_the_compile_cache_in_the_checkout(cache_config, monkeypatch):
+    from pathlib import Path
+
+    from repro.core.cluster import COMPILE_CACHE_DIR
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    Ignis.start()
+    checkout = Path(__file__).resolve().parents[1]
+    assert COMPILE_CACHE_DIR == checkout / ".jax_cache"
+    assert cache_config.jax_compilation_cache_dir == str(checkout / ".jax_cache")
+
+
+def test_chip_smoke_refuses_a_host_without_a_tpu():
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    r = subprocess.run([sys.executable, str(root / "chip_smoke.py")], cwd=root,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"},
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode != 0
+    assert "found platform 'cpu'" in r.stderr
+    assert '"ok"' not in r.stdout
