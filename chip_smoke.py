@@ -68,11 +68,6 @@ CG_ITERS = 30
 CG_RTOL = 1e-4
 JOB_N = 1 << 24
 
-#: kernel modes compared in the aggregation phase, and the tier the first
-#: one must select
-MODES = ("auto", "off")
-TIER = "compiled"
-
 I32 = np.iinfo(np.int32)
 
 
@@ -194,8 +189,11 @@ def _worker(chips: int, kind: str = "dataflow", cluster=None, **props):
     return IWorker(cluster, kind)
 
 
-def _devices_of(x) -> int:
-    return len(x.sharding.device_set)
+def _rows_per_device(valid) -> list:
+    """Valid rows of each shard of ``valid``, in shard order, each summed on
+    the device that holds it."""
+    shards = sorted(valid.addressable_shards, key=lambda s: s.index[0].start or 0)
+    return [int(jnp.sum(s.data, dtype=jnp.int32)) for s in shards]
 
 
 def _kernel_lines(df) -> list[str]:
@@ -203,7 +201,10 @@ def _kernel_lines(df) -> list[str]:
             if "kernel=" in ln or ln.startswith("kernels:")]
 
 
-def aggregation(rng, chips: int, n: int = AGG_RECORDS):
+def aggregation(rng, chips: int, n: int = AGG_RECORDS,
+                modes=("auto", "off"), tier: str = "compiled"):
+    """``modes[0]`` must run on the kernel ``tier``; ``modes[1]`` (one chip
+    only) must match it bit for bit."""
     keys = zipf_keys(rng, n)
     vals = rng.integers(0, 100, n, dtype=np.int32)
     ref = np.bincount(keys, weights=vals, minlength=KEYS).astype(np.int64)
@@ -212,7 +213,7 @@ def aggregation(rng, chips: int, n: int = AGG_RECORDS):
     # four chips run the kernel tier only: the oracle twin is the one-chip
     # run's bit-identity check, and each extra sort stage there costs minutes
     # of compile
-    modes = MODES if chips == 1 else MODES[:1]
+    modes = modes if chips == 1 else modes[:1]
     outs = {}
     for mode in modes:
         t0 = time.perf_counter()
@@ -226,23 +227,25 @@ def aggregation(rng, chips: int, n: int = AGG_RECORDS):
         totals, rows = _dense_totals(data["key"], data["value"], valid)
         jax.block_until_ready((totals, rows))
         km = w.metrics("kernels")
+        per_dev = _rows_per_device(valid)
         log(f"aggregation[{mode}]: {n} records, {int(present.sum())} keys, "
-            f"{time.perf_counter() - t0:.2f}s, devices={_devices_of(valid)} "
+            f"{time.perf_counter() - t0:.2f}s, output rows per device={per_dev} "
             f"kernel_hits={km['kernel_hits']} "
             f"kernel_fallbacks={km['kernel_fallbacks']} "
             f"overflow_retries={w.metrics('shuffle')['overflow_retries']}")
         for ln in _kernel_lines(red):
             log(f"  {ln}")
-        check(_devices_of(valid) == chips, f"aggregation[{mode}] not on {chips} devices")
+        check(len(per_dev) == chips and min(per_dev) > 0,
+              f"aggregation[{mode}]: rows not spread over {chips} devices: {per_dev}")
         if mode == "off":
             check(km["kernel_hits"] == 0, "kernels=off ran a kernel")
         else:
             text = red.explain()
-            check(f"segment_reduce[{TIER}]" in text,
-                  f"aggregation[{mode}] did not run segment_reduce[{TIER}]")
+            check(f"segment_reduce[{tier}]" in text,
+                  f"aggregation[{mode}] did not run segment_reduce[{tier}]")
             if chips > 1:
-                check(f"bucket_route[{TIER}]" in text,
-                      f"aggregation[{mode}] did not run bucket_route[{TIER}]")
+                check(f"bucket_route[{tier}]" in text,
+                      f"aggregation[{mode}] did not run bucket_route[{tier}]")
             check(km["kernel_fallbacks"] == 0,
                   f"aggregation[{mode}] fell back {km['kernel_fallbacks']} times")
         check(np.array_equal(np.asarray(totals), ref),
@@ -313,12 +316,13 @@ def cg(rng, chips: int, n: int = CG_N, iters: int = CG_ITERS):
     x_df = w.call("cg_app", w.parallelize(b), iters=iters)
     data, valid = w.void_call(rows_app, x_df)
     x = np.asarray(data)
+    per_dev = _rows_per_device(valid)
     dt = time.perf_counter() - t0
     err = _rel_err(x, cg_reference(b, iters))
     log(f"cg: {n} unknowns, {iters} iterations, {dt:.2f}s, "
-        f"devices={_devices_of(data)}, rel_l2_err={err!r} (tolerance {CG_RTOL})")
-    check(_devices_of(data) == chips, f"cg: not on {chips} devices")
-    check(bool(np.asarray(valid).all()), "cg: output rows missing")
+        f"rows per device={per_dev}, rel_l2_err={err!r} (tolerance {CG_RTOL})")
+    check(len(per_dev) == chips and sum(per_dev) == n and min(per_dev) > 0,
+          f"cg: rows not spread over {chips} devices: {per_dev}")
     check(err <= CG_RTOL, f"cg: relative error {err} > {CG_RTOL}")
 
 
@@ -357,6 +361,11 @@ def job(rng, chips: int, n: int = JOB_N, iters: int = CG_ITERS):
     check(np.array_equal(got_hist, ref_hist), "job: key counts differ from NumPy")
 
 
+ONE_CHIP_PHASES = (aggregation, sort, stencil, cg, job)
+#: the cross-chip paths, cheapest first
+FOUR_CHIP_PHASES = (sort, cg, aggregation)
+
+
 def _peak_bytes() -> list:
     return [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in jax.devices()]
 
@@ -381,8 +390,7 @@ def main(argv=None):
         raise SystemExit(f"--four-chips needs 4 TPU devices; jax found {len(devs)}")
     log(f"device: {dev.platform} {dev.device_kind} x{len(devs)}, "
         f"executors={chips}, jax {jax.__version__}, seed={args.seed}")
-    phases = ([aggregation, sort, cg] if chips > 1
-              else [aggregation, sort, stencil, cg, job])
+    phases = FOUR_CHIP_PHASES if chips > 1 else ONE_CHIP_PHASES
     rngs = np.random.default_rng(args.seed).spawn(len(phases))
     log(f"setup: {time.perf_counter() - t0:.2f}s")
     for phase, rng in zip(phases, rngs):

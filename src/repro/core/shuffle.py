@@ -108,14 +108,68 @@ def _pack_exchange(dest, payload, axis, p, C, route=None):
         buf = buf.at[slot].set(xs)
         return buf[: p * C]
 
-    packed = jax.tree.map(pack, payload)
+    return _exchange(jax.tree.map(pack, payload), axis, p, C), overflow, max_fill
+
+
+def _pack_sorted(dest, payload, end, axis, p, C):
+    """``_pack_exchange`` for rows already in destination order: PSRS rows
+    are sorted by key, so ``dest`` is non-decreasing and bucket d is one run
+    of rows. The rows from ``end`` on (the invalid tail) are not sent and
+    take no capacity: after a wide stage, padding fills half of a block,
+    and routed to the last executor it overflowed that bucket. A gather
+    fills the buckets, so the stage carries no second argsort and no
+    scatter (each a sort on XLA:TPU). Bit-identical to ``_pack_exchange``
+    on the rows it keeps."""
+    bounds = jnp.searchsorted(dest, jnp.arange(p + 1, dtype=dest.dtype), side="left")
+    starts = bounds[:p].astype(jnp.int32)
+    ends = jnp.minimum(bounds[1:].astype(jnp.int32), end)
+    counts = jnp.maximum(ends - starts, 0)
+    overflow = jnp.maximum(counts - C, 0).sum().astype(jnp.int32)
+    max_fill = counts.max().astype(jnp.int32)
+    slot = jnp.arange(p * C, dtype=jnp.int32)
+    d, i = slot // C, slot % C
+    keep = i < counts[d]
+    src = jnp.where(keep, starts[d] + i, 0)
+
+    def pack(x):
+        m = keep.reshape((-1,) + (1,) * (x.ndim - 1))
+        return jnp.where(m, x[src], jnp.zeros((), x.dtype))
+
+    return _exchange(jax.tree.map(pack, payload), axis, p, C), overflow, max_fill
+
+
+def _exchange(packed, axis, p, C):
+    """all_to_all of (p·C, …) buffers: bucket d of every shard to shard d.
+
+    Leaves narrower than 32 bits travel widened to 32: XLA:TPU compiles an
+    all_to_all of a large bool or int8 buffer in about a minute (one of
+    2^25 rows per chip for v5e), and one of int32 in under a second."""
 
     def xchg(x):
-        y = x.reshape(p, C, *x.shape[1:])
+        y = _widen(x).reshape(p, C, *x.shape[1:])
         y = jax.lax.all_to_all(y, axis, split_axis=0, concat_axis=0, tiled=False)
-        return y.reshape(p * C, *x.shape[1:])
+        return _narrow(y.reshape(p * C, *x.shape[1:]), x.dtype)
 
-    return jax.tree.map(xchg, packed), overflow, max_fill
+    return jax.tree.map(xchg, packed)
+
+
+def _widen(x):
+    """``x`` as a 32-bit array that ``_narrow`` turns back, bit for bit."""
+    if x.dtype == jnp.bool_:
+        return x.astype(jnp.int32)
+    if x.dtype.itemsize >= 4:
+        return x
+    bits = jax.lax.bitcast_convert_type(x, jnp.dtype(f"uint{8 * x.dtype.itemsize}"))
+    return bits.astype(jnp.uint32)
+
+
+def _narrow(y, dtype):
+    if dtype == jnp.bool_:
+        return y != 0
+    if jnp.dtype(dtype).itemsize >= 4:
+        return y
+    bits = y.astype(jnp.dtype(f"uint{8 * jnp.dtype(dtype).itemsize}"))
+    return jax.lax.bitcast_convert_type(bits, dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -144,8 +198,6 @@ def sort_stage(ctx: IContext, keys, valid, data, C: int, post=None):
         out = post(keys[order], valid[order], jax.tree.map(lambda x: x[order], data))
         return out, zero, zero
 
-    n_local = keys.shape[0] // p
-
     def f(k, v, d):
         big = _sentinel(k.dtype)
         ks = jnp.where(v, k, big)
@@ -153,14 +205,21 @@ def sort_stage(ctx: IContext, keys, valid, data, C: int, post=None):
         ks, vs = ks[order], v[order]
         ds = jax.tree.map(lambda x: x[order], d)
         korig = k[order]
-        # regular sampling: p evenly spaced local samples
-        idx = ((jnp.arange(1, p + 1) * n_local) // (p + 1)).astype(jnp.int32)
+        # regular sampling: the valid rows (which sort first) at quantiles
+        # 0, 1/p, …, (p-1)/p — sampling the padding's sentinels would drag
+        # pivots to the top of the key range and starve the last executors
+        q, r = jnp.divmod(v.sum(dtype=jnp.int32), p)
+        j = jnp.arange(p, dtype=jnp.int32)
+        idx = j * q + (j * r) // p  # j * n_valid // p, no overflow
         samples = ks[idx]
         all_samples = jax.lax.all_gather(samples, ctx.axis, tiled=True)  # (p·p,)
-        pivots = jnp.sort(all_samples)[p - 1 :: p][: p - 1]
+        # the middle sample of quantile i's group is global pivot i
+        pivots = jnp.sort(all_samples)[p + p // 2 - 1 :: p][: p - 1]
         dest = jnp.searchsorted(pivots, ks, side="right").astype(jnp.int32)
+        # invalid rows sort last; those after the last valid row stay home
+        end = jnp.max(jnp.where(vs, jnp.arange(ks.shape[0], dtype=jnp.int32), -1)) + 1
         payload = {"k": korig, "valid": vs, "data": ds}
-        out, overflow, fill = _pack_exchange(dest, payload, ctx.axis, p, C)
+        out, overflow, fill = _pack_sorted(dest, payload, end, ctx.axis, p, C)
         # local merge
         big2 = _sentinel(out["k"].dtype)
         km = jnp.where(out["valid"], out["k"], big2)

@@ -345,13 +345,25 @@ class ShuffleManager:
         n = max(int(n), 1)
         return sorted({min(int(c), n) for c in self.kernels.blocks})
 
-    def _tune_reduce(self, b: Block, op: str, sel) -> int:
-        """Tuned block size for the segment kernel on this block's aval."""
+    def _post_rows(self, sig, rows: int) -> int:
+        """Rows per shard that a sort stage's post hook receives: the whole
+        block at p=1, else the p*C receive buffer at the remembered (or
+        default) capacity. A capacity retry's larger buffer keeps the block
+        tuned here."""
+        p = self.p
+        if p <= 1:
+            return rows
+        with self._plan_lock:
+            factor = self._capacity.get((sig, rows, p), self.default_factor)
+        return p * sh.capacity_for(factor, rows // p, p)
+
+    def _tune_reduce(self, sig, b: Block, op: str, sel) -> int:
+        """Tuned block size for the segment kernel at the rows it runs on."""
         from repro.kernels.segment_reduce.ops import segment_totals
 
         leaf = jax.tree_util.tree_leaves(b.data["value"])[0]
         D = () if leaf.ndim == 1 else leaf.shape[1:]
-        n = b.capacity // max(self.p, 1)  # the kernel runs on one shard
+        n = self._post_rows(sig, b.capacity)
         key = ("segment_reduce", op, str(leaf.dtype), D, n,
                sel.interpret, jax.default_backend())
 
@@ -446,7 +458,7 @@ class ShuffleManager:
         sel = self.kernels.select("segment_reduce") if op is not None else None
         if sel is not None:
             try:
-                blk = self._tune_reduce(b, op, sel)
+                blk = self._tune_reduce(sig, b, op, sel)
             except Exception as e:
                 self.kernels.degrade(sel, e)
                 sel = None

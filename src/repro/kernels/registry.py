@@ -43,13 +43,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.metrics import Counters
+from repro.core.properties import REGISTRY as PROPS
 from repro.kernels.ssd_scan.prefix import TILE
 
 #: dtypes the kernel tier computes natively (bool rides as i32)
 SUPPORTED_DTYPES = ("float32", "int32")
 
 #: autotune candidates: rows per grid step, whole (8, 128) tiles each
-DEFAULT_BLOCKS = "8192,32768,131072"
+DEFAULT_BLOCKS = PROPS["ignis.kernels.blocks"].default
 
 
 def compiled_backend() -> bool:

@@ -7,10 +7,12 @@ subprocess suite (tests/_distributed_main.py); here we cover everything
 observable at p = 1, including join fan-out overflow (which is p-independent).
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core import ICluster, IProperties, IWorker
+from repro.core import shuffle as sh
 
 
 @pytest.fixture
@@ -268,3 +270,22 @@ def test_spark_mode_shuffle_parity(worker):
     for w in (worker, ws):
         outs.append([int(x) for x in w.parallelize(data).sort().collect()])
     assert outs[0] == outs[1] == sorted(int(v) for v in data)
+
+
+@pytest.mark.parametrize("dtype", ["bool", "int8", "uint8", "int16", "float16",
+                                   "bfloat16", "int32", "float32"])
+def test_exchange_widening_round_trips_bit_for_bit(dtype):
+    # exchanged leaves travel 32 bits wide (shuffle._exchange); narrowing
+    # must give back every bit, NaN payloads and -0.0 included
+    bits = np.random.default_rng(0).integers(0, 1 << 16, 512).astype(np.uint16)
+    if dtype == "bool":
+        x = jnp.asarray(bits % 2 == 1)
+    else:
+        dt = jnp.dtype(dtype)
+        raw = jnp.asarray(bits).astype(jnp.dtype(f"uint{8 * dt.itemsize}"))
+        x = raw.astype(dt) if dt.itemsize == 4 else jax.lax.bitcast_convert_type(raw, dt)
+    wide = sh._widen(x)
+    assert wide.dtype.itemsize == 4
+    back = sh._narrow(wide, x.dtype)
+    assert back.dtype == x.dtype
+    assert np.asarray(back).tobytes() == np.asarray(x).tobytes()

@@ -13,6 +13,7 @@ process at a time may load the TPU library, and every test worker imports
 this module. Keep every such compile in this one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -121,3 +122,7 @@ def test_hash_stage_compiles_on_four_v5e_chips(topo):
     assert p == 4
     assert "all-to-all" in text
     assert "tpu_custom_call" in text
+    # every leaf, the bool validity included, crosses 32 bits wide: a bool
+    # all-to-all of 2^25 rows per chip takes about a minute to compile
+    a2a = re.findall(r"= (\w+)\[[^\]]*\]\S* all-to-all\(", text)
+    assert a2a and set(a2a) <= {"s32", "u32", "f32"}, a2a
