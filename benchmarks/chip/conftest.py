@@ -1,0 +1,10 @@
+"""Puts the checkout root and ``src`` on ``sys.path`` for the benchmark's
+own CPU tests, so they import ``benchmarks.chip`` and ``repro`` however
+pytest was started."""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+for p in (os.path.join(ROOT, "src"), ROOT):
+    if p not in sys.path:
+        sys.path.insert(0, p)
