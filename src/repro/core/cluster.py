@@ -33,6 +33,7 @@ from repro.core.partition import Block, block_aval, concat_blocks, from_host, pl
 from repro.core.properties import IProperties
 from repro.core.textlambda import ISource
 from repro.kernels.registry import DEFAULT_BLOCKS, KernelRegistry
+from repro.profile.spans import span
 
 
 #: persistent compile cache used when JAX_COMPILATION_CACHE_DIR is unset:
@@ -549,8 +550,13 @@ class IWorker:
         def fn(parent_results):
             faults.check("reshard", kind="importData", src=src_worker.name,
                          dst=self.name)
+            with span("import:" + df.node.op, src=src_worker.name, dst=self.name,
+                      blocks=len(parent_results[0])):
+                return reshard(parent_results[0])
+
+        def reshard(blocks):
             out = []
-            for b in parent_results[0]:
+            for b in blocks:
                 if self.mode == "spark" or src_worker.mode == "spark":
                     # the paper's pipe: serialize → host → deserialize
                     data = pickle.loads(pickle.dumps(jax.device_get(b.data)))
@@ -629,7 +635,9 @@ class IWorker:
 
         def fn(parent_results):
             ctx = worker.context.bind(params)  # execution-time binding
-            out_cell["value"] = app(ctx, *worker._native_args(ctx, parent_results))
+            args = worker._native_args(ctx, parent_results)
+            with span("native:" + name, call="voidCall"):
+                out_cell["value"] = app(ctx, *args)
             return []  # void: no blocks enter the lineage
 
         node = TaskNode(f"voidCall:{name}", parents, fn=fn, narrow=False)
@@ -663,7 +671,9 @@ class IWorker:
 
         def fn(parent_results):
             ctx = worker.context.bind(params)  # execution-time binding
-            out = app(ctx, *worker._native_args(ctx, parent_results))
+            args = worker._native_args(ctx, parent_results)
+            with span("native:" + name, call="call"):
+                out = app(ctx, *args)
             if comm_mod.is_handle(out):
                 # app handed back an in-flight collective: keep it
                 # nonblocking — chain the Block adaptation onto the handle
@@ -693,7 +703,8 @@ class IWorker:
         def block_fn(parent_blocks):
             ctx = worker.context.bind(params)  # execution-time binding
             b = parent_blocks[0]
-            out = app(ctx, b.data, b.valid)
+            with span("native:" + name, call="callPartitions"):
+                out = app(ctx, b.data, b.valid)
             if comm_mod.is_handle(out):
                 out = out.wait()  # block-wise lineage is the sync point here
             if isinstance(out, Block):

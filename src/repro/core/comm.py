@@ -58,6 +58,7 @@ from jax.sharding import PartitionSpec as P
 from repro.core import compat, faults
 from repro.core.context import IContext
 from repro.core.metrics import Counters
+from repro.profile.spans import first_call
 
 _handle_ids = itertools.count()
 
@@ -276,7 +277,7 @@ class CommEngine:
                 self._plans.popitem(last=False)
                 self.stats["coll_plan_evictions"] += 1
         building.set()
-        return fn
+        return first_call("coll", fn)
 
     def clear(self):
         """Drop every compiled plan (benchmarks use this to measure the

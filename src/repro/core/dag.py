@@ -22,13 +22,18 @@ individual blocks by walking the original narrow chain.
 from __future__ import annotations
 
 import itertools
-import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
 from repro.core import comm, faults
+from repro.core import executor as ex
 from repro.core.metrics import Counters
+from repro.profile.spans import first_call, span
+
+#: what the engine's ``stage:``/``wide:`` spans time: the host's dispatch of
+#: the work, not the device's run of it (jax dispatches asynchronously)
+DISPATCH = "host dispatch"
 
 _ids = itertools.count()
 
@@ -131,9 +136,6 @@ class DagEngine:
         # the stage's XLA compile will pay for itself
         self.fusion_mode = fusion_mode
         self.cost_model = cost_model  # repro.profile.cost.CostModel | None
-        # live span hook (docs/profiling.md): JobTracer.attach_worker sets
-        # this to its buffer's record(name, cat, t0, t1, **args)
-        self.trace_hook = None
         self.plan_cache_size = plan_cache_size
         self._plan_cache: "OrderedDict[tuple, Callable]" = OrderedDict()
         # gang-scheduled tasks (core/job.py) enter one engine from several
@@ -158,6 +160,7 @@ class DagEngine:
             "speculative_retries": 0,  # straggler duplicates launched
             "handle_awaits": 0,  # CollHandle-valued node results awaited
             "fusion_deferred": 0,  # chains the cost policy left unfused
+            "vmap_misses": 0,  # jitted row maps built (executor._vmapped)
         })
 
     # ---- planner (stage compilation) ----------------------------------------
@@ -330,7 +333,7 @@ class DagEngine:
             while len(self._plan_cache) > self.plan_cache_size:
                 self._plan_cache.popitem(last=False)
                 self.stats["plan_cache_evictions"] += 1
-        return fn
+        return first_call("stage", fn)
 
     # ---- evaluation ---------------------------------------------------------
     def evaluate(self, node: TaskNode, memo: dict | None = None):
@@ -385,7 +388,9 @@ class DagEngine:
             for parents_i in zip(*iters):
                 faults.check("dag.block", op=node.op, block=len(out), fused=False)
                 self.stats["iter_block_computes"] += 1
+                misses = ex.vmap_misses()
                 b = node.block_fn(list(parents_i))
+                self.stats["vmap_misses"] += ex.vmap_misses() - misses
                 out.append(b)
                 yield b
             # fully consumed ⇒ the node is materialised: record it in the
@@ -422,18 +427,16 @@ class DagEngine:
         if node.narrow and node.block_fn is not None:
             nblocks = len(parent_results[0]) if parent_results else 0
             out = []
+            misses = ex.vmap_misses()
             for i in range(nblocks):
                 faults.check("dag.block", op=node.op, block=i, fused=False)
                 out.append(node.block_fn([pr[i] for pr in parent_results]))
+            self.stats["vmap_misses"] += ex.vmap_misses() - misses
             return out
         faults.check("dag.node", op=node.op)
         self.stats["wide_computes"] += 1
-        hook = self.trace_hook
-        t0 = time.perf_counter() if hook is not None else 0.0
-        out = node.fn(parent_results)
-        if hook is not None:
-            hook(f"wide:{node.op}", "engine", t0, time.perf_counter(),
-                 op=node.op, node=node.id)
+        with span("wide:" + node.op, op=node.op, node=node.id, time=DISPATCH):
+            out = node.fn(parent_results)
         if comm.is_handle(out):
             # a wide/native node may return a nonblocking collective handle
             # (e.g. an SPMD app handing back an in-flight result); the
@@ -450,18 +453,14 @@ class DagEngine:
         from repro.core.partition import Block
 
         parent_blocks = self._eval(stage.head.parents[0], memo, plans)
-        hook = self.trace_hook
-        t0 = time.perf_counter() if hook is not None else 0.0
         out = []
-        for i, b in enumerate(parent_blocks):
-            faults.check("dag.block", op=stage.tail.op, block=i, fused=True)
-            fn = self._compiled(stage, b)
-            data, valid = fn(b.data, b.valid)
-            out.append(Block(data, valid))
-        if hook is not None:
-            hook(f"stage:{stage.tail.op}", "engine", t0, time.perf_counter(),
-                 ops=len(stage.nodes), blocks=len(out),
-                 stage=stage.describe())
+        with span("stage:" + stage.tail.op, ops=len(stage.nodes),
+                  blocks=len(parent_blocks), time=DISPATCH):
+            for i, b in enumerate(parent_blocks):
+                faults.check("dag.block", op=stage.tail.op, block=i, fused=True)
+                fn = self._compiled(stage, b)
+                data, valid = fn(b.data, b.valid)
+                out.append(Block(data, valid))
         for n in stage.nodes:  # telemetry parity with the unfused path
             n.compute_count += 1
         self.stats["node_computes"] += len(stage.nodes)

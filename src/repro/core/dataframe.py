@@ -32,6 +32,7 @@ from repro.core.dag import TaskNode, node_sig
 from repro.core.partition import Block, concat_blocks, from_host, split_block, to_host
 from repro.core.shuffle_plan import _static_token, fn_token
 from repro.core.textlambda import resolve
+from repro.profile.spans import span
 
 
 def _pack_default(row):
@@ -45,6 +46,27 @@ def _pack_default(row):
     if isinstance(row, dict) and set(row) == {"key", "value"}:
         return row["key"]
     return row
+
+
+def _host(action: str, value, convert=None):
+    """An action's result brought to the host: ``value`` fetched from the
+    device (``fetch:<action>``, mostly a wait for the device), then turned
+    into Python objects by ``convert`` (``collect:<action>``)."""
+    with span("fetch:" + action, "action"):
+        value = jax.device_get(value)
+    if convert is None:
+        return value
+    with span("collect:" + action, "action"):
+        return convert(value)
+
+
+def _rows(blocks) -> list:
+    """The valid rows of host blocks ``[(data, valid), ...]``, in order."""
+    return [r for data, valid in blocks for r in to_host(Block(data, valid))]
+
+
+def _pairs(blocks) -> list:
+    return [(b.data, b.valid) for b in blocks]
 
 
 class IDataFrame:
@@ -189,9 +211,8 @@ class IDataFrame:
         fn = resolve(fn)
 
         def act(blocks):
-            for b in blocks:
-                for row in to_host(b):
-                    fn(row)
+            for row in _host("foreach", _pairs(blocks), _rows):
+                fn(row)
 
         return self._submit("foreach", act, job=job, group=group)
 
@@ -454,7 +475,7 @@ class IDataFrame:
             counts = [ex.count_block(b) for b in blocks]
             return comm.CollHandle(
                 "action.count", None, counts,
-                transform=lambda cs: sum(int(c) for c in jax.device_get(cs)))
+                transform=lambda cs: _host("count", cs, lambda h: sum(int(c) for c in h)))
 
         return self._submit("count", act, job=job, group=group)
 
@@ -469,7 +490,7 @@ class IDataFrame:
             vfn = lambda a, c: jax.tree.map(fn, a, c)  # noqa: E731
             out = ex.pairwise_reduce(b.data, b.valid, vfn, identity)
             return comm.CollHandle("action.reduce", None, out,
-                                   transform=jax.device_get)
+                                   transform=lambda v: _host("reduce", v))
 
         return self._submit("reduce", act, job=job, group=group)
 
@@ -515,39 +536,34 @@ class IDataFrame:
         return self.min_async(key_fn).result()
 
     def _extreme_of(self, blocks, key_fn, largest: bool):
+        action = "max" if largest else "min"
         b = concat_blocks(blocks)
         if key_fn is None:
             op = jnp.maximum if largest else jnp.minimum
             sent = sh._sentinel_low if largest else sh._sentinel
             ident = jax.tree.map(lambda x: sent(x.dtype), b.data)
             vfn = lambda a, c: jax.tree.map(op, a, c)  # noqa: E731
-            return jax.device_get(ex.pairwise_reduce(b.data, b.valid, vfn, ident))
+            return _host(action, ex.pairwise_reduce(b.data, b.valid, vfn, ident))
         key_fn = resolve(key_fn)
         keys = jax.vmap(key_fn)(b.data)
         sent = (sh._sentinel_low if largest else sh._sentinel)(keys.dtype)
         masked = jnp.where(b.valid, keys, sent)
-        i = int(jax.device_get(jnp.argmax(masked) if largest else jnp.argmin(masked)))
-        if not bool(jax.device_get(b.valid[i])):
+        i = int(_host(action, jnp.argmax(masked) if largest else jnp.argmin(masked)))
+        if not bool(_host(action, b.valid[i])):
             # a valid row tying the sentinel can shadow the winner; fall back
             # to the host (also the empty-frame path)
-            rows = [r for blk in blocks for r in to_host(blk)]
+            rows = _host(action, _pairs(blocks), _rows)
             if not rows:
                 raise ValueError("max()/min() with key_fn on an empty dataframe")
             pick = max if largest else min
             return pick(rows, key=lambda r: float(np.asarray(key_fn(r))))
-        return jax.device_get(jax.tree.map(lambda x: x[i], b.data))
+        return _host(action, jax.tree.map(lambda x: x[i], b.data))
 
     def collect_async(self, job=None, group=None):
         def act(blocks):
-            def tx(_ready):
-                out = []
-                for b in blocks:
-                    out.extend(to_host(b))
-                return out
-
             return comm.CollHandle(
-                "action.collect", None,
-                [(b.data, b.valid) for b in blocks], transform=tx)
+                "action.collect", None, _pairs(blocks),
+                transform=lambda ready: _host("collect", ready, _rows))
 
         return self._submit("collect", act, job=job, group=group)
 
@@ -564,7 +580,7 @@ class IDataFrame:
         def run(memo):
             out = []
             for b in worker.engine.evaluate_blocks_iter(node, memo=memo):
-                out.extend(to_host(b))
+                out.extend(_host("take", [(b.data, b.valid)], _rows))
                 if len(out) >= k:
                     break
             return out[:k]
@@ -582,14 +598,18 @@ class IDataFrame:
         return self.top_async(k, key_fn).result()
 
     @staticmethod
-    def _kv_dict(blocks) -> dict:
-        rows = [r for b in blocks for r in to_host(b)]
-        return {int(np.asarray(r["key"])): int(np.asarray(r["value"])) for r in rows}
+    def _kv_dict(action: str):
+        """The blocks_fn of a keyed count: a {key: count} dict on the host."""
+        def convert(blocks) -> dict:
+            return {int(np.asarray(r["key"])): int(np.asarray(r["value"]))
+                    for r in _rows(blocks)}
+
+        return lambda blocks: _host(action, _pairs(blocks), convert)
 
     def count_by_key_async(self, job=None):
         ones = self.map_values(lambda v: jnp.int32(1))
         red = ones.reduce_by_key(lambda a, b: a + b, 0)
-        return red._submit("countByKey", self._kv_dict, job=job)
+        return red._submit("countByKey", self._kv_dict("countByKey"), job=job)
 
     def count_by_key(self) -> dict:
         return self.count_by_key_async().result()
@@ -597,7 +617,7 @@ class IDataFrame:
     def count_by_value_async(self, job=None):
         kv = self.map(lambda r: {"key": r, "value": jnp.int32(1)})
         red = kv.reduce_by_key(lambda a, b: a + b, 0)
-        return red._submit("countByValue", self._kv_dict, job=job)
+        return red._submit("countByValue", self._kv_dict("countByValue"), job=job)
 
     def count_by_value(self) -> dict:
         return self.count_by_value_async().result()

@@ -7,6 +7,7 @@ compaction on device).
 """
 from __future__ import annotations
 
+import threading
 import weakref
 from typing import Callable
 
@@ -14,12 +15,20 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.partition import Block
+from repro.profile.spans import first_call
 
 # jit cache keyed on the user fn object: a dataframe op's fn is created once
 # at graph-build time, so re-evaluating the same node hits the trace cache
 # (compute-heavy row fns — e.g. Minebench's SHA-256 — would otherwise run
 # eagerly op-by-op).
 _VMAP_JIT: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+_misses = threading.local()
+
+
+def vmap_misses() -> int:
+    """Misses of the jitted-map cache on this thread so far (the engine
+    adds the ones its blocks cause to ``stages/vmap_misses``)."""
+    return getattr(_misses, "n", 0)
 
 
 def _vmapped(fn: Callable) -> Callable:
@@ -28,11 +37,13 @@ def _vmapped(fn: Callable) -> Callable:
     except TypeError:  # unhashable/unweakrefable fn
         return jax.vmap(fn)
     if j is None:
+        _misses.n = vmap_misses() + 1
         j = jax.jit(jax.vmap(fn))
         try:
             _VMAP_JIT[fn] = j
         except TypeError:
             pass
+        return first_call("vmap", j)
     return j
 
 

@@ -33,6 +33,8 @@ from typing import Any, Callable, Optional
 from repro.core import comm, faults
 from repro.core.dag import _OverlayMemo
 from repro.core.metrics import Counters, MetricsTree, warn_deprecated
+from repro.profile.spans import span
+from repro.profile.tracer import task_lane
 
 _task_ids = itertools.count()
 
@@ -48,6 +50,15 @@ def task_history_key(task) -> tuple:
     if node is not None:
         return (task.kind, node_sig(node))
     return (task.kind, task.name.split("(", 1)[0])
+
+
+def _traced(task) -> tuple:
+    """(buffer, span args) for a task of a traced job, else (None, {})."""
+    tracer = task.tracer
+    if tracer is None:
+        return None, {}
+    return tracer.buffer, {"job": task.job, "task": task.name,
+                           "lane": task_lane(task), "kind": task.kind}
 
 
 PENDING = "pending"
@@ -66,8 +77,9 @@ class JobTask:
         "group", "node", "lock", "attempt", "attempts", "lock_dropped",
         # profiling (docs/profiling.md): the thread that ran the body, the
         # serialisation-lock wait that preceded it, the compute→settle
-        # phase boundary timestamps, and the job's tracer (if attached)
-        "tid", "t_lock_wait", "t_compute_end", "t_settle_end", "tracer",
+        # phase boundary timestamps, and the job's tracer (if attached) and
+        # name
+        "tid", "t_lock_wait", "t_compute_end", "t_settle_end", "tracer", "job",
     )
 
     def __init__(self, name: str, kind: str, worker, fn: Callable[[], Any],
@@ -96,6 +108,7 @@ class JobTask:
         self.t_compute_end = 0.0
         self.t_settle_end = 0.0
         self.tracer = None
+        self.job = ""
         # gang scheduling (docs/collectives.md): the group communicator this
         # task executes on (None → the worker's base mesh), the TaskNode it
         # materialises (for inter-group reshard edges), and the serialisation
@@ -357,9 +370,11 @@ class JobScheduler:
         lock = task.lock
         lock_wait = 0.0
         if lock is not None:
-            t0 = time.perf_counter()
-            lock.acquire()
-            lock_wait = time.perf_counter() - t0
+            buf, args = _traced(task)
+            with span("lock_wait", "sched", into=buf, **args):
+                t0 = time.perf_counter()
+                lock.acquire()
+                lock_wait = time.perf_counter() - t0
         claimed: list = []
         try:
             self._run_locked(task, claimed, lock_wait=lock_wait)
@@ -434,6 +449,13 @@ class JobScheduler:
             self.stats["max_concurrent"] = max(
                 self.stats["max_concurrent"], self._running
             )
+        # live spans (docs/profiling.md §schema): the task, and inside it one
+        # compute and one settle span per attempt
+        buf, args = _traced(task)
+        whole = span("task:" + task.kind, "task", label=task.name, into=buf, **args)
+        whole.__enter__()
+        compute = span("compute", "task", **args)
+        compute.__enter__()
         task.t_start = time.perf_counter()
         task.t_lock_wait = lock_wait
         task.tid = threading.get_ident()
@@ -453,6 +475,9 @@ class JobScheduler:
                 # nodes that lost blocks repair block-wise inside the engine.
                 while True:
                     try:
+                        if compute is None:  # a retry after a failed settle
+                            compute = span("compute", "task", **args)
+                            compute.__enter__()
                         faults.check("job.task", name=task.name, kind=task.kind,
                                      attempt=task.attempt)
                         # the runner (not the task fn) binds the communicator:
@@ -476,8 +501,13 @@ class JobScheduler:
                         # retry loop, re-running the task fn and re-issuing
                         # its collectives.
                         task.t_compute_end = time.perf_counter()
-                        task.result = self._settle(task, task.result,
-                                                   pending, held)
+                        compute.__exit__(None, None, None)
+                        compute = None
+                        with span("settle", "task", **args) as settling:
+                            task.result = self._settle(task, task.result,
+                                                       pending, held)
+                            if settling is not None:
+                                settling.args["overlapped"] = task.lock_dropped
                         task.t_settle_end = time.perf_counter()
                         break
                     except BaseException as e:
@@ -495,7 +525,14 @@ class JobScheduler:
                 self._local.held_locks = held
         except BaseException as e:  # surfaced via IFuture.result()
             error = e
+        if compute is not None:
+            compute.__exit__(None, None, None)
         task.t_end = time.perf_counter()
+        final = getattr(whole, "args", None)
+        if final is not None:
+            final.update(state=DONE if error is None else FAILED,
+                         attempt=task.attempt)
+        whole.__exit__(None, None, None)
         with self._lock:
             self._running -= 1
             if error is None:
@@ -515,7 +552,7 @@ class JobScheduler:
 
     def _observe(self, task: JobTask, error):
         """Feed the profiling surfaces as a task resolves: the attached
-        tracer's span buffer (docs/profiling.md), and — for successful
+        tracer's cost history (docs/profiling.md), and — for successful
         runs — the owning worker's cost-model task history, which is what
         ``ignis.task.speculative.timeout=auto`` derives deadlines from.
         Observation must never poison the DAG: failures are swallowed."""
@@ -790,7 +827,7 @@ class IJob:
             return self._evaluator(_worker, _t)(_node, self._task_memo(_t))
 
         t.fn = fn
-        t.tracer = self.tracer
+        t.tracer, t.job = self.tracer, self.name
         self._node_tasks[node] = t
         self.tasks.append(t)
         self.scheduler.submit(t)
@@ -844,7 +881,7 @@ class IJob:
             return blocks_fn(blocks)
 
         t.fn = fn
-        t.tracer = self.tracer
+        t.tracer, t.job = self.tracer, self.name
         self.tasks.append(t)
         self.scheduler.submit(t)
         fut = IFuture(t)

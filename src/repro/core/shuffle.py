@@ -21,6 +21,14 @@ shard_map body — so sort→segment-heads→segmented-reduce chains (reduceByKe
 distinct, groupByKey) execute as ONE wide stage instead of three dispatches.
 Post hooks are valid because PSRS/hash routing sends equal keys to one shard:
 no key segment ever spans a shard boundary.
+
+Inside a stage the device work carries ``jax.named_scope`` names, which the
+profiler's trace keeps on each op (docs/profiling.md §schema): ``ARGSORT``
+(the sort of the keys), ``PERMUTE`` (every gather that applies a sort's
+order to a leaf, ``x[order]``), ``EXCHANGE`` (sampling, bucket packing and
+the ``all_to_all``), ``MERGE`` (the local merge after the exchange, and the
+join's sort-merge) and ``POST`` (the post hook). Scopes name ops only; they
+cost nothing at run time and leave the stage's executable as it was.
 """
 from __future__ import annotations
 
@@ -33,6 +41,27 @@ from jax.sharding import PartitionSpec as P
 from repro.core import compat
 from repro.core.context import IContext
 from repro.core.partition import Block
+
+_scope = jax.named_scope
+
+# named scopes, not properties
+ARGSORT = "ignis.argsort"  # props: ignore
+PERMUTE = "ignis.permute"  # props: ignore
+EXCHANGE = "ignis.exchange"  # props: ignore
+MERGE = "ignis.merge"  # props: ignore
+POST = "ignis.post"  # props: ignore
+
+
+def _argsort(keys):
+    with _scope(ARGSORT):
+        return jnp.argsort(keys, stable=True)
+
+
+def _permute(order, *trees):
+    """Each leaf of ``trees`` gathered by ``order``: a sort's order applied."""
+    with _scope(PERMUTE):
+        out = jax.tree.map(lambda x: x[order], trees)
+    return out if len(trees) > 1 else out[0]
 
 
 def _sentinel(dtype):
@@ -86,29 +115,28 @@ def _pack_exchange(dest, payload, axis, p, C, route=None):
     buffer is bit-identical; only the sliced-off overflow scratch slot can
     differ (duplicate writes, different order).
     """
-    n = dest.shape[0]
-    if route is not None:
-        pos, keep, counts = route(dest)
-        order = None  # rows scatter from row order directly
-        slot = jnp.where(keep, dest * C + pos, p * C)
-    else:
-        order = jnp.argsort(dest, stable=True)
-        ds = dest[order]
-        counts = jnp.bincount(ds, length=p)
-        starts = jnp.cumsum(counts) - counts
-        pos = jnp.arange(n) - starts[ds]
-        keep = pos < C
-        slot = jnp.where(keep, ds * C + pos, p * C)  # overflow → scratch slot
-    overflow = (n - keep.sum()).astype(jnp.int32)
-    max_fill = counts.max().astype(jnp.int32)
+    with _scope(EXCHANGE):
+        n = dest.shape[0]
+        if route is not None:
+            pos, keep, counts = route(dest)
+            slot = jnp.where(keep, dest * C + pos, p * C)
+        else:
+            order = _argsort(dest)
+            ds, payload = _permute(order, dest, payload)
+            counts = jnp.bincount(ds, length=p)
+            starts = jnp.cumsum(counts) - counts
+            pos = jnp.arange(n) - starts[ds]
+            keep = pos < C
+            slot = jnp.where(keep, ds * C + pos, p * C)  # overflow → scratch slot
+        overflow = (n - keep.sum()).astype(jnp.int32)
+        max_fill = counts.max().astype(jnp.int32)
 
-    def pack(x):
-        xs = x if order is None else x[order]
-        buf = jnp.zeros((p * C + 1, *x.shape[1:]), x.dtype)
-        buf = buf.at[slot].set(xs)
-        return buf[: p * C]
+        def pack(x):
+            buf = jnp.zeros((p * C + 1, *x.shape[1:]), x.dtype)
+            buf = buf.at[slot].set(x)
+            return buf[: p * C]
 
-    return _exchange(jax.tree.map(pack, payload), axis, p, C), overflow, max_fill
+        return _exchange(jax.tree.map(pack, payload), axis, p, C), overflow, max_fill
 
 
 def _pack_sorted(dest, payload, end, axis, p, C):
@@ -120,22 +148,23 @@ def _pack_sorted(dest, payload, end, axis, p, C):
     fills the buckets, so the stage carries no second argsort and no
     scatter (each a sort on XLA:TPU). Bit-identical to ``_pack_exchange``
     on the rows it keeps."""
-    bounds = jnp.searchsorted(dest, jnp.arange(p + 1, dtype=dest.dtype), side="left")
-    starts = bounds[:p].astype(jnp.int32)
-    ends = jnp.minimum(bounds[1:].astype(jnp.int32), end)
-    counts = jnp.maximum(ends - starts, 0)
-    overflow = jnp.maximum(counts - C, 0).sum().astype(jnp.int32)
-    max_fill = counts.max().astype(jnp.int32)
-    slot = jnp.arange(p * C, dtype=jnp.int32)
-    d, i = slot // C, slot % C
-    keep = i < counts[d]
-    src = jnp.where(keep, starts[d] + i, 0)
+    with _scope(EXCHANGE):
+        bounds = jnp.searchsorted(dest, jnp.arange(p + 1, dtype=dest.dtype), side="left")
+        starts = bounds[:p].astype(jnp.int32)
+        ends = jnp.minimum(bounds[1:].astype(jnp.int32), end)
+        counts = jnp.maximum(ends - starts, 0)
+        overflow = jnp.maximum(counts - C, 0).sum().astype(jnp.int32)
+        max_fill = counts.max().astype(jnp.int32)
+        slot = jnp.arange(p * C, dtype=jnp.int32)
+        d, i = slot // C, slot % C
+        keep = i < counts[d]
+        src = jnp.where(keep, starts[d] + i, 0)
 
-    def pack(x):
-        m = keep.reshape((-1,) + (1,) * (x.ndim - 1))
-        return jnp.where(m, x[src], jnp.zeros((), x.dtype))
+        def pack(x):
+            m = keep.reshape((-1,) + (1,) * (x.ndim - 1))
+            return jnp.where(m, x[src], jnp.zeros((), x.dtype))
 
-    return _exchange(jax.tree.map(pack, payload), axis, p, C), overflow, max_fill
+        return _exchange(jax.tree.map(pack, payload), axis, p, C), overflow, max_fill
 
 
 def _exchange(packed, axis, p, C):
@@ -194,39 +223,41 @@ def sort_stage(ctx: IContext, keys, valid, data, C: int, post=None):
     zero = jnp.zeros((), jnp.int32)
     if p == 1:
         big = _sentinel(keys.dtype)
-        order = jnp.argsort(jnp.where(valid, keys, big), stable=True)
-        out = post(keys[order], valid[order], jax.tree.map(lambda x: x[order], data))
+        order = _argsort(jnp.where(valid, keys, big))
+        ks, vs, ds = _permute(order, keys, valid, data)
+        with _scope(POST):
+            out = post(ks, vs, ds)
         return out, zero, zero
 
     def f(k, v, d):
         big = _sentinel(k.dtype)
         ks = jnp.where(v, k, big)
-        order = jnp.argsort(ks, stable=True)
-        ks, vs = ks[order], v[order]
-        ds = jax.tree.map(lambda x: x[order], d)
-        korig = k[order]
-        # regular sampling: the valid rows (which sort first) at quantiles
-        # 0, 1/p, …, (p-1)/p — sampling the padding's sentinels would drag
-        # pivots to the top of the key range and starve the last executors
-        q, r = jnp.divmod(v.sum(dtype=jnp.int32), p)
-        j = jnp.arange(p, dtype=jnp.int32)
-        idx = j * q + (j * r) // p  # j * n_valid // p, no overflow
-        samples = ks[idx]
-        all_samples = jax.lax.all_gather(samples, ctx.axis, tiled=True)  # (p·p,)
-        # the middle sample of quantile i's group is global pivot i
-        pivots = jnp.sort(all_samples)[p + p // 2 - 1 :: p][: p - 1]
-        dest = jnp.searchsorted(pivots, ks, side="right").astype(jnp.int32)
-        # invalid rows sort last; those after the last valid row stay home
-        end = jnp.max(jnp.where(vs, jnp.arange(ks.shape[0], dtype=jnp.int32), -1)) + 1
+        order = _argsort(ks)
+        ks, vs, ds, korig = _permute(order, ks, v, d, k)
+        with _scope(EXCHANGE):
+            # regular sampling: the valid rows (which sort first) at quantiles
+            # 0, 1/p, …, (p-1)/p — sampling the padding's sentinels would drag
+            # pivots to the top of the key range and starve the last executors
+            q, r = jnp.divmod(v.sum(dtype=jnp.int32), p)
+            j = jnp.arange(p, dtype=jnp.int32)
+            idx = j * q + (j * r) // p  # j * n_valid // p, no overflow
+            samples = ks[idx]
+            all_samples = jax.lax.all_gather(samples, ctx.axis, tiled=True)  # (p·p,)
+            # the middle sample of quantile i's group is global pivot i
+            pivots = jnp.sort(all_samples)[p + p // 2 - 1 :: p][: p - 1]
+            dest = jnp.searchsorted(pivots, ks, side="right").astype(jnp.int32)
+            # invalid rows sort last; those after the last valid row stay home
+            end = jnp.max(jnp.where(vs, jnp.arange(ks.shape[0], dtype=jnp.int32), -1)) + 1
         payload = {"k": korig, "valid": vs, "data": ds}
         out, overflow, fill = _pack_sorted(dest, payload, end, ctx.axis, p, C)
-        # local merge
-        big2 = _sentinel(out["k"].dtype)
-        km = jnp.where(out["valid"], out["k"], big2)
-        order2 = jnp.argsort(km, stable=True)
-        res = jax.tree.map(lambda x: x[order2], out)
+        with _scope(MERGE):
+            big2 = _sentinel(out["k"].dtype)
+            km = jnp.where(out["valid"], out["k"], big2)
+            res = _permute(_argsort(km), out)
+        with _scope(POST):
+            out = post(res["k"], res["valid"], res["data"])
         return (
-            post(res["k"], res["valid"], res["data"]),
+            out,
             jax.lax.psum(overflow, ctx.axis),
             jax.lax.pmax(fill, ctx.axis),
         )
@@ -249,15 +280,19 @@ def hash_stage(ctx: IContext, keys, valid, data, C: int, post=None, route=None):
     p = ctx.executors
     zero = jnp.zeros((), jnp.int32)
     if p == 1:
-        return post(keys, valid, data), zero, zero
+        with _scope(POST):
+            return post(keys, valid, data), zero, zero
 
     def f(k, v, d):
-        dest = (_hash_u32(k) % jnp.uint32(p)).astype(jnp.int32)
-        dest = jnp.where(v, dest, p - 1)  # park invalid rows anywhere stable
+        with _scope(EXCHANGE):
+            dest = (_hash_u32(k) % jnp.uint32(p)).astype(jnp.int32)
+            dest = jnp.where(v, dest, p - 1)  # park invalid rows anywhere stable
         payload = {"k": k, "valid": v, "data": d}
         out, overflow, fill = _pack_exchange(dest, payload, ctx.axis, p, C, route)
+        with _scope(POST):
+            out = post(out["k"], out["valid"], out["data"])
         return (
-            post(out["k"], out["valid"], out["data"]),
+            out,
             jax.lax.psum(overflow, ctx.axis),
             jax.lax.pmax(fill, ctx.axis),
         )
@@ -289,8 +324,9 @@ def join_stage(ctx: IContext, lk, lvalid, lvals, rk, rvalid, rvals,
         return rows, ok, zero, zero, zero, fovf.astype(jnp.int32)
 
     def f(lk_, lv_, ld_, rk_, rv_, rd_):
-        ldest = jnp.where(lv_, (_hash_u32(lk_) % jnp.uint32(p)).astype(jnp.int32), p - 1)
-        rdest = jnp.where(rv_, (_hash_u32(rk_) % jnp.uint32(p)).astype(jnp.int32), p - 1)
+        with _scope(EXCHANGE):
+            ldest = jnp.where(lv_, (_hash_u32(lk_) % jnp.uint32(p)).astype(jnp.int32), p - 1)
+            rdest = jnp.where(rv_, (_hash_u32(rk_) % jnp.uint32(p)).astype(jnp.int32), p - 1)
         lout, lovf, lfill = _pack_exchange(
             ldest, {"k": lk_, "valid": lv_, "data": ld_}, ctx.axis, p, Cl, route_l)
         rout, rovf, rfill = _pack_exchange(
@@ -516,12 +552,15 @@ def make_group_post(G: int):
 
 def local_join(lk, lvalid, lvals, rk, rvalid, rvals, max_matches: int):
     """Sort-merge join on one shard. Returns dict rows of capacity n_left·M."""
+    with _scope(MERGE):
+        return _local_join(lk, lvalid, lvals, rk, rvalid, rvals, max_matches)
+
+
+def _local_join(lk, lvalid, lvals, rk, rvalid, rvals, max_matches: int):
     big = _sentinel(rk.dtype)
     rs = jnp.where(rvalid, rk, big)
-    order = jnp.argsort(rs, stable=True)
-    rs = rs[order]
-    rv = jax.tree.map(lambda x: x[order], rvals)
-    rvalid_s = rvalid[order]
+    order = _argsort(rs)
+    rs, rv, rvalid_s = _permute(order, rs, rvals, rvalid)
 
     lo = jnp.searchsorted(rs, lk, side="left")
     hi = jnp.searchsorted(rs, lk, side="right")

@@ -46,6 +46,7 @@ from repro.core import shuffle as sh
 from repro.core.metrics import Counters
 from repro.core.partition import Block, block_aval as _block_aval, block_devices, place_block
 from repro.kernels.registry import KernelRegistry, builtin_reduce_op
+from repro.profile.spans import first_call, span
 
 
 class _Opaque(Exception):
@@ -263,7 +264,7 @@ class ShuffleManager:
             while len(self._plans) > self.plan_cache_size:
                 self._plans.popitem(last=False)
                 self.stats["wide_plan_evictions"] += 1
-        return fn
+        return first_call("wide", fn)
 
     def _account(self, b: Block, C: int):
         p = self.p
@@ -333,7 +334,8 @@ class ShuffleManager:
 
     def _time_calls(self, fn, *args) -> float:
         """Median-free micro-timer: one warm-up (compile), two timed runs."""
-        jax.block_until_ready(fn(*args))
+        with span("compile:autotune"):
+            jax.block_until_ready(fn(*args))
         t0 = time.perf_counter()
         jax.block_until_ready(fn(*args))
         jax.block_until_ready(fn(*args))

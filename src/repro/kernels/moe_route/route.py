@@ -70,6 +70,7 @@ def bucket_route_fwd(dest, p: int, capacity: int, block: int = 8192,
             jax.ShapeDtypeStruct((p, 1, LANES), jnp.int32),
         ],
         interpret=interpret,
+        name="bucket_route",
     )(d)
     return (pos.reshape(n_pad)[:N], keep.reshape(n_pad)[:N].astype(bool),
             counts[:, 0, 0])

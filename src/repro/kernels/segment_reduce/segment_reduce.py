@@ -61,5 +61,6 @@ def segment_reduce_fwd(values, boundaries, op: str = "sum", block: int = 8192,
         out_shape=jax.ShapeDtypeStruct(cols.shape, values.dtype),
         scratch_shapes=[pltpu.VMEM((1, LANES), values.dtype)],
         interpret=interpret,
+        name="segment_reduce",
     )(cols, flags)
     return out.reshape(D, n_pad)[:, :N].T

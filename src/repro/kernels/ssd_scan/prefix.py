@@ -135,5 +135,6 @@ def prefix_scan_fwd(x, op: str = "sum", block: int = 8192, interpret: bool = Fal
         out_shape=jax.ShapeDtypeStruct(xl.shape, x.dtype),
         scratch_shapes=[pltpu.VMEM((1, LANES), x.dtype)],
         interpret=interpret,
+        name="prefix_scan",
     )(xl)
     return out.reshape(n_pad)[:N]

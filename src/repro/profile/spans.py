@@ -14,19 +14,117 @@ interleave, while same-thread spans always nest. The exporter therefore
 keys ``tid`` on the executing thread and carries the lane/gang label in
 ``args["lane"]``, which is what the schema tests validate
 (tests/test_profile.py).
+
+``span()`` is the one way the program opens a span (docs/profiling.md
+§schema). While a ``JobTracer`` is attached it opens
+``jax.profiler.TraceAnnotation("ignis:" + name)`` — so under
+``jax.profiler`` the span lands in the trace beside the device ops, on the
+same clock — and records the same interval in the ``TraceBuffer`` of the
+traced job whose task runs on this thread. Buffer spans are stamped with
+``clock()``, the clock the profiler's host events use (CLOCK_REALTIME): a
+buffer span and its ``ignis:`` copy in the trace differ by the profile's
+start time only. While no tracer is attached, ``span()`` reads one module
+flag and returns a shared no-op context: it creates no span object and
+makes no call into JAX.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
+import time
 from dataclasses import dataclass, field
+
+import jax
+
+PREFIX = "ignis:"
+
+_NOOP = contextlib.nullcontext()
+_on = False  # a JobTracer is attached somewhere in the process
+_tracers = 0
+_state_lock = threading.Lock()
+_tls = threading.local()  # .buffer: where this thread's spans go
+
+
+def clock() -> float:
+    """Seconds on the profiler's host clock (CLOCK_REALTIME, which TSL's
+    ``TraceMe`` stamps)."""
+    return time.time_ns() / 1e9
+
+
+def tracing(delta: int):
+    """Count attached tracers up or down (``JobTracer``); spans are live
+    while the count is positive."""
+    global _tracers, _on
+    with _state_lock:
+        _tracers = max(0, _tracers + delta)
+        _on = _tracers > 0
+
+
+def span(name: str, cat: str = "engine", label: str | None = None,
+         into: "TraceBuffer | None" = None, **args):
+    """A context manager timing ``name`` (docs/profiling.md §schema).
+
+    The profiler sees ``ignis:<name>`` with ``args``; the buffer records
+    ``label`` (default ``name``) under ``cat``. ``into`` names the buffer,
+    and nested spans on this thread inherit it. Without ``into`` a span
+    goes where the enclosing span on this thread went, and nowhere outside
+    any."""
+    if not _on:
+        return _NOOP
+    buf = into if into is not None else getattr(_tls, "buffer", None)
+    return _NOOP if buf is None else _Live(buf, name, cat, label, args)
+
+
+def first_call(site: str, fn):
+    """``fn`` as a jit cache hands it out on a miss. While tracing is on,
+    its first call (the trace and the compile) runs inside
+    ``span("compile:" + site)``; otherwise ``fn`` itself."""
+    if not _on:
+        return fn
+    pending = [True]
+
+    def call(*a, **kw):
+        if pending:
+            pending.clear()
+            with span("compile:" + site):
+                return fn(*a, **kw)
+        return fn(*a, **kw)
+
+    return call
+
+
+class _Live:
+    """One open span: a profiler annotation and its buffer copy."""
+
+    __slots__ = ("buf", "name", "cat", "label", "args", "_ann", "_t0", "_prev")
+
+    def __init__(self, buf, name, cat, label, args):
+        self.buf, self.name, self.cat, self.label = buf, name, cat, label
+        self.args = args  # the buffer copy reads them at exit
+
+    def __enter__(self):
+        self._prev = getattr(_tls, "buffer", None)
+        _tls.buffer = self.buf
+        self._ann = jax.profiler.TraceAnnotation(PREFIX + self.name, **self.args)
+        self._ann.__enter__()
+        self._t0 = clock()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = clock()
+        self._ann.__exit__(*exc)
+        _tls.buffer = self._prev
+        self.buf.add(Span(self.label or self.name, self.cat, self._t0, t1,
+                          threading.get_ident(), dict(self.args)))
+        return False
 
 
 @dataclass(frozen=True)
 class Span:
     name: str      # "compute", "lock_wait", "settle", "stage:...", ...
     cat: str       # "task" | "engine" | "sched"
-    t0: float      # perf_counter seconds
+    t0: float      # seconds on clock()
     t1: float
     tid: int       # executing thread id
     args: dict = field(default_factory=dict)  # lane, kind, attempt, ...
@@ -47,11 +145,6 @@ class TraceBuffer:
         with self._lock:
             self._spans.append(span)
 
-    def record(self, name: str, cat: str, t0: float, t1: float,
-               tid: int | None = None, **args):
-        self.add(Span(name, cat, t0, t1,
-                      threading.get_ident() if tid is None else tid, args))
-
     def spans(self) -> list[Span]:
         with self._lock:
             return list(self._spans)
@@ -69,7 +162,7 @@ def to_chrome(spans: list[Span], process_name: str = "ignis") -> dict:
     """Render spans as a Chrome trace JSON object.
 
     ``ts``/``dur`` are microseconds relative to the earliest span (Chrome
-    renders absolute perf_counter values poorly); every distinct tid gets
+    renders absolute clock values poorly); every distinct tid gets
     a ``thread_name`` metadata event naming the lanes it ran."""
     if not spans:
         return {"traceEvents": [], "displayTimeUnit": "ms"}

@@ -4,27 +4,28 @@ Attach to a job before running actions; export a Chrome-trace timeline
 after::
 
     tracer = JobTracer()
-    tracer.attach(job)            # task spans: lock-wait/compute/settle
-    tracer.attach_worker(worker)  # engine spans + metrics "profile/" mount
+    tracer.attach(job)            # spans of the job's tasks, live
+    tracer.attach_worker(worker)  # metrics "profile/" mount + cost model
     ... run actions ...
     tracer.save("trace.json")     # open in chrome://tracing / Perfetto
 
-Task phases come from timestamps the scheduler already stamps on each
-``JobTask`` (core/job.py): ``t_start``→``t_end`` is the task body,
-``t_lock_wait`` the serialisation-lock wait that preceded it,
-``t_compute_end``→``t_settle_end`` the collective settle (the window the
-nonblocking design overlaps with the next task — visible in the timeline
-as a settle span running beside a peer's compute). Engine spans
-(fused-stage and wide-node computes) stream in live through the
-``DagEngine.trace_hook`` while attached. The tracer also feeds every
-finished task's duration into its ``CostModel``'s history, which is what
+Every span is live: the scheduler (core/job.py) opens ``lock_wait``, the
+task's own span and its ``compute`` and ``settle`` phases through
+``spans.span()`` as they happen, with the traced job's buffer, and every
+span opened inside the task on the same thread — engine ``stage:``/
+``wide:``, ``compile:``, ``fetch:``/``collect:``, ``import:``, ``native:``
+— lands in the same buffer (docs/profiling.md §schema). While any tracer
+is attached, the same spans go to ``jax.profiler``'s trace as
+``ignis:<name>``. The tracer also feeds every finished task's duration
+into its ``CostModel``'s history, which is what
 ``ignis.task.speculative.timeout=auto`` reads.
 """
 from __future__ import annotations
 
 import threading
-import time
+import weakref
 
+from repro.profile import spans as _spans
 from repro.profile.cost import CostModel
 from repro.profile.spans import Span, TraceBuffer, save_chrome, to_chrome
 
@@ -49,71 +50,51 @@ class JobTracer:
         self.cost = cost_model or CostModel()
         self._lock = threading.Lock()
         self._jobs: list = []
-        self._workers: list = []
-        self._t0 = time.perf_counter()
+        self._live = None  # finalizer that takes this tracer's spans off
 
     # ------------------------------------------------------------------
     # attachment
     # ------------------------------------------------------------------
     def attach(self, job) -> "JobTracer":
-        """Trace ``job``: the scheduler notifies this tracer as each task
-        resolves (span emission + cost-history observation)."""
+        """Trace ``job``: its tasks open their spans into this tracer's
+        buffer, and the scheduler notifies it as each task resolves
+        (cost-history observation). Turns ``spans.span()`` on."""
         job.tracer = self
         with self._lock:
             self._jobs.append(job)
+            if self._live is None:
+                _spans.tracing(+1)
+                self._live = weakref.finalize(self, _spans.tracing, -1)
         return self
 
     def attach_worker(self, worker) -> "JobTracer":
-        """Trace ``worker``'s engine (fused-stage/wide-node spans via the
-        ``DagEngine.trace_hook``) and mount ``profile/`` on its metrics
-        tree; also adopts the worker engine's cost model so observations
-        and decisions share state."""
-        worker.engine.trace_hook = self.buffer.record
+        """Mount ``profile/`` on ``worker``'s metrics tree and adopt the
+        worker engine's cost model, so observations and decisions share
+        state. The engine's spans come with the traced job's tasks."""
         if getattr(worker.engine, "cost_model", None) is not None:
             self.cost = worker.engine.cost_model
         if hasattr(worker, "mount_metrics"):
             worker.mount_metrics("profile", self.summary)
-        with self._lock:
-            self._workers.append(worker)
         return self
 
     def detach(self):
         with self._lock:
             jobs, self._jobs = self._jobs, []
-            workers, self._workers = self._workers, []
+            live, self._live = self._live, None
         for job in jobs:
             if job.tracer is self:
                 job.tracer = None
-        for w in workers:
-            if getattr(w.engine, "trace_hook", None) is self.buffer.record:
-                w.engine.trace_hook = None
+        if live is not None:
+            live()
 
     # ------------------------------------------------------------------
     # scheduler callback (core/job.py `_run_locked` end)
     # ------------------------------------------------------------------
     def task_done(self, task):
-        """Emit the task's phase spans from its stamped timestamps and feed
-        the cost history. Called once per resolved task, failed or not."""
+        """Feed the task's duration into the cost history. Called once per
+        resolved task, failed or not; its spans were recorded live."""
         if not task.t_end:
             return
-        lane = task_lane(task)
-        tid = task.tid or 0
-        args = {"lane": lane, "kind": task.kind, "task": task.name,
-                "state": task.state, "attempt": task.attempt}
-        if task.t_lock_wait > 0:
-            self.buffer.add(Span("lock_wait", "sched",
-                                 task.t_start - task.t_lock_wait,
-                                 task.t_start, tid, dict(args)))
-        # whole-task span; compute/settle children nest inside it
-        self.buffer.add(Span(task.name, "task", task.t_start, task.t_end,
-                             tid, dict(args)))
-        t_compute_end = task.t_compute_end or task.t_end
-        self.buffer.add(Span("compute", "task", task.t_start,
-                             min(t_compute_end, task.t_end), tid, dict(args)))
-        if task.t_settle_end > t_compute_end:
-            self.buffer.add(Span("settle", "task", t_compute_end,
-                                 min(task.t_settle_end, task.t_end), tid,
-                                 {**args, "overlapped": task.lock_dropped}))
         key = self.task_key(task)
         if key is not None:
             self.cost.observe_task(key, task.t_end - task.t_start)

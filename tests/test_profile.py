@@ -2,9 +2,11 @@
 validation, replay determinism, facade equivalence over the unified metrics
 tree, the typed property registry, and the two cost-model decisions
 (cost-aware fusion boundaries, auto speculative timeouts)."""
+import gc
 import json
 import warnings
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -18,12 +20,14 @@ from repro.profile import (
     Span,
     TaskRecord,
     Trace,
+    TraceBuffer,
     capture,
     predicted_vs_measured,
     simulate,
     to_chrome,
     validate,
 )
+from repro.profile import spans
 
 
 @pytest.fixture
@@ -118,6 +122,131 @@ def test_tracer_summary_and_profile_mount(worker):
     assert worker.metrics("profile")["tasks"] == summ["tasks"]
     assert job.metrics("profile")["tasks"] == summ["tasks"]
     tracer.detach()
+
+
+# ---------------------------------------------------------------------------
+# live spans: one span() API, on the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def test_span_without_a_tracer_is_the_shared_noop(monkeypatch):
+    gc.collect()  # tracers that earlier tests dropped without detach() go off
+    assert not spans._on
+
+    def no_jax(*a, **kw):
+        raise AssertionError("span() called into JAX with tracing off")
+
+    monkeypatch.setattr(spans.jax.profiler, "TraceAnnotation", no_jax)
+    buf = TraceBuffer()
+    s = spans.span("stage:map", op="map", into=buf)
+    assert s is spans.span("collect:count", "action") is spans._NOOP
+    with s:
+        pass
+    fn = lambda x: x  # noqa: E731
+    assert spans.first_call("vmap", fn) is fn
+    assert len(buf) == 0
+
+
+def test_span_is_live_while_a_tracer_is_attached():
+    gc.collect()
+    before = spans._tracers
+    tracer = JobTracer()
+    tracer.attach(IJob("live"))
+    assert spans._on and spans._tracers == before + 1
+    with spans.span("outer", into=tracer.buffer, k=1):
+        with spans.span("inner", "action"):  # inherits the buffer
+            pass
+    with spans.span("stray"):  # no span around it on this thread: nowhere
+        pass
+    got = tracer.spans()
+    assert [(s.name, s.cat) for s in got] == [("inner", "action"), ("outer", "engine")]
+    inner, outer = got
+    assert outer.t0 <= inner.t0 <= inner.t1 <= outer.t1 and inner.tid == outer.tid
+    assert outer.args == {"k": 1}
+    # buffer spans are stamped on the profiler's host clock (CLOCK_REALTIME)
+    assert abs(outer.t1 - spans.clock()) < 60.0
+    tracer.detach()
+    assert spans._tracers == before
+
+
+def test_spans_from_many_threads_all_land():
+    """Threads opening spans into one buffer at once lose none, and each
+    thread's nested spans inherit its own binding."""
+    import sys
+    import threading
+
+    tracer = JobTracer()
+    tracer.attach(IJob("threads"))
+    n_threads, n_spans = 12, 200
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        def work():
+            for _ in range(n_spans):
+                with spans.span("outer", into=tracer.buffer):
+                    with spans.span("inner"):
+                        pass
+
+        threads = [threading.Thread(target=work) for _ in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+    got = tracer.spans()
+    assert len(got) == 2 * n_threads * n_spans
+    assert sum(s.name == "inner" for s in got) == n_threads * n_spans
+    assert validate(tracer.to_chrome()) == []
+    tracer.detach()
+
+
+def test_engine_and_action_spans_nest_in_their_task(worker):
+    tracer = JobTracer()
+    tracer.attach_worker(worker)
+    job = IJob("engine")
+    tracer.attach(job)
+    keys = np.arange(64, dtype=np.int32) % 8
+    df = worker.parallelize(keys).map(lambda x: x + 1).map(lambda x: x * 2)
+    got = df.count_by_value_async(job=job).result()
+    assert got == {int(k): 8 for k in (np.arange(8) + 1) * 2}
+    recorded = tracer.spans()
+    names = {s.name for s in recorded}
+    assert {"lock_wait", "compute", "settle", "stage:map", "wide:reduceByKey",
+            "compile:stage", "compile:wide", "fetch:countByValue",
+            "collect:countByValue"} <= names
+    task = next(s for s in recorded if s.cat == "task"
+                and s.name.startswith("countByValue("))
+    assert task.args["job"] == "engine" and task.args["kind"] == "action"
+    assert task.args["state"] == "done" and "lane" in task.args
+    inside = [s for s in recorded if s.cat in ("engine", "action")]
+    assert all(task.t0 <= s.t0 <= s.t1 <= task.t1 and s.tid == task.tid
+               for s in inside)
+    assert all(s.args["time"] == "host dispatch" for s in inside
+               if s.name.startswith(("stage:", "wide:")))
+    assert validate(tracer.to_chrome()) == []
+    tracer.detach()
+
+
+def test_compile_spans_cover_the_first_call_after_a_miss(worker):
+    tracer = JobTracer()
+    job = IJob("misses")
+    tracer.attach(job)
+    src = worker.parallelize(np.arange(32, dtype=np.int32))
+    fn = lambda x: x + 3  # noqa: E731
+    misses = worker.metrics("stages")["vmap_misses"]
+    assert src.map(fn).count_async(job=job).result() == 32
+    assert worker.metrics("stages")["vmap_misses"] == misses + 1
+    assert [s.name for s in tracer.spans()].count("compile:vmap") == 1
+    # a rebuilt lineage over the same function hits the cache: no compile
+    assert src.map(fn).count_async(job=job).result() == 32
+    assert worker.metrics("stages")["vmap_misses"] == misses + 1
+    assert [s.name for s in tracer.spans()].count("compile:vmap") == 1
+    tracer.detach()
+    # the counter counts untraced misses too
+    assert src.map(lambda x: x - 3).count() == 32
+    assert worker.metrics("stages")["vmap_misses"] == misses + 2
 
 
 # ---------------------------------------------------------------------------
