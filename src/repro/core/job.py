@@ -544,7 +544,10 @@ class JobScheduler:
                 self.stats["tasks_failed"] += 1
             task.fn = None  # never called again — release the closure (and
             # with it the job memo / blocks it pins) once the task resolves
-            dependents = list(task.dependents)
+            # hand the back-edges off: a resolved task left in a deps <->
+            # dependents cycle keeps its result blocks on the device until
+            # Python's cyclic collector runs, jobs later
+            dependents, task.dependents = task.dependents, []
         self._observe(task, error)
         self._resolve(task)
         for dep in dependents:
@@ -592,7 +595,7 @@ class JobScheduler:
             self._unclaim_locked(task)
             task.fn = None
             self.stats["tasks_failed"] += 1
-            dependents = list(task.dependents)
+            dependents, task.dependents = task.dependents, []
         self._resolve(task)
         for dep in dependents:
             self._fail(dep, error)

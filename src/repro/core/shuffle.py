@@ -24,11 +24,14 @@ no key segment ever spans a shard boundary.
 
 Inside a stage the device work carries ``jax.named_scope`` names, which the
 profiler's trace keeps on each op (docs/profiling.md §schema): ``ARGSORT``
-(the sort of the keys), ``PERMUTE`` (every gather that applies a sort's
-order to a leaf, ``x[order]``), ``EXCHANGE`` (sampling, bucket packing and
-the ``all_to_all``), ``MERGE`` (the local merge after the exchange, and the
-join's sort-merge) and ``POST`` (the post hook). Scopes name ops only; they
-cost nothing at run time and leave the stage's executable as it was.
+(a sort of the keys: in the sort stage the one ``lax.sort`` that carries
+the payload with the keys, see ``_sort_carry``), ``PERMUTE`` (a gather
+that applies a sort's order to a leaf, ``x[order]``: in the sort stage only
+the fallback for leaves with trailing dimensions, which cannot ride in the
+sort), ``EXCHANGE`` (sampling, bucket packing and the ``all_to_all``),
+``MERGE`` (the local merge after the exchange, and the join's sort-merge)
+and ``POST`` (the post hook). Scopes name ops only; they cost nothing at
+run time and leave the stage's executable as it was.
 """
 from __future__ import annotations
 
@@ -62,6 +65,60 @@ def _permute(order, *trees):
     with _scope(PERMUTE):
         out = jax.tree.map(lambda x: x[order], trees)
     return out if len(trees) > 1 else out[0]
+
+
+def _rides(x) -> bool:
+    """Whether a leaf can be an operand of ``lax.sort``: one value per row."""
+    return x.ndim == 1
+
+
+def _distinct(leaves):
+    """``leaves`` with each array object once, in first-seen order, and for
+    each leaf its position in that list."""
+    first, uniq = {}, []
+    for x in leaves:
+        if id(x) not in first:
+            first[id(x)] = len(uniq)
+            uniq.append(x)
+    return uniq, [first[id(x)] for x in leaves]
+
+
+def sort_gathers(data) -> int:
+    """Leaves of a block's ``data`` that each local sort of ``sort_stage``
+    still gathers after the sort: those that cannot ride in it."""
+    return sum(not _rides(x) for x in _distinct(jax.tree.leaves(data))[0])
+
+
+def _sort_carry(keys, valid, *trees):
+    """``(keys, valid, *trees)`` with their rows in one stable order: valid
+    rows first, by key, then the invalid rows.
+
+    One ``lax.sort`` compares ``(~valid, keys)`` lexicographically and
+    carries every 1-D leaf as an operand, each distinct array once (the keys
+    are often a leaf of the data); the sorted ``valid`` is the negated first
+    key. A leaf with trailing dimensions cannot be an operand: an iota rides
+    instead and those leaves are gathered by it. The valid rows come out in
+    the order ``_permute(_argsort(where(valid, keys, _sentinel)), ...)``
+    gives them, and a valid key equal to the sentinel still sorts before
+    every invalid row. With no payload beyond the two keys, rows that
+    compare equal are equal bit for bit, so the sort need not be stable
+    (XLA:TPU carries one more operand, an iota, for a stable sort)."""
+    leaves, treedef = jax.tree.flatten(trees)
+    uniq, index = _distinct([valid, keys, *leaves])
+    rest = range(2, len(uniq))
+    carried = [1] + [i for i in rest if _rides(uniq[i])]
+    gathered = [i for i in rest if not _rides(uniq[i])]
+    with _scope(ARGSORT):
+        operands = [~valid] + [uniq[i] for i in carried]
+        if gathered:
+            operands.append(jnp.arange(keys.shape[0], dtype=jnp.int32))
+        out = jax.lax.sort(operands, num_keys=2, is_stable=len(operands) > 2)
+    got = {0: ~out[0], **{i: out[j] for j, i in enumerate(carried, 1)}}
+    if gathered:
+        with _scope(PERMUTE):
+            got.update({i: uniq[i][out[-1]] for i in gathered})
+    res = [got[i] for i in index]
+    return (res[1], res[0], *jax.tree.unflatten(treedef, res[2:]))
 
 
 def _sentinel(dtype):
@@ -222,23 +279,22 @@ def sort_stage(ctx: IContext, keys, valid, data, C: int, post=None):
     p = ctx.executors
     zero = jnp.zeros((), jnp.int32)
     if p == 1:
-        big = _sentinel(keys.dtype)
-        order = _argsort(jnp.where(valid, keys, big))
-        ks, vs, ds = _permute(order, keys, valid, data)
+        ks, vs, ds = _sort_carry(keys, valid, data)
         with _scope(POST):
             out = post(ks, vs, ds)
         return out, zero, zero
 
-    def f(k, v, d):
+    def f(*arrays):
+        k, v, d = jax.tree.unflatten(treedef, [arrays[i] for i in index])
+        korig, vs, ds = _sort_carry(k, v, d)
         big = _sentinel(k.dtype)
-        ks = jnp.where(v, k, big)
-        order = _argsort(ks)
-        ks, vs, ds, korig = _permute(order, ks, v, d, k)
+        ks = jnp.where(vs, korig, big)
         with _scope(EXCHANGE):
             # regular sampling: the valid rows (which sort first) at quantiles
             # 0, 1/p, …, (p-1)/p — sampling the padding's sentinels would drag
             # pivots to the top of the key range and starve the last executors
-            q, r = jnp.divmod(v.sum(dtype=jnp.int32), p)
+            n_valid = v.sum(dtype=jnp.int32)
+            q, r = jnp.divmod(n_valid, p)
             j = jnp.arange(p, dtype=jnp.int32)
             idx = j * q + (j * r) // p  # j * n_valid // p, no overflow
             samples = ks[idx]
@@ -246,29 +302,30 @@ def sort_stage(ctx: IContext, keys, valid, data, C: int, post=None):
             # the middle sample of quantile i's group is global pivot i
             pivots = jnp.sort(all_samples)[p + p // 2 - 1 :: p][: p - 1]
             dest = jnp.searchsorted(pivots, ks, side="right").astype(jnp.int32)
-            # invalid rows sort last; those after the last valid row stay home
-            end = jnp.max(jnp.where(vs, jnp.arange(ks.shape[0], dtype=jnp.int32), -1)) + 1
+        # the invalid rows, which sort after every valid row, stay home
         payload = {"k": korig, "valid": vs, "data": ds}
-        out, overflow, fill = _pack_sorted(dest, payload, end, ctx.axis, p, C)
+        out, overflow, fill = _pack_sorted(dest, payload, n_valid, ctx.axis, p, C)
         with _scope(MERGE):
-            big2 = _sentinel(out["k"].dtype)
-            km = jnp.where(out["valid"], out["k"], big2)
-            res = _permute(_argsort(km), out)
+            rk, rv, rd = _sort_carry(out["k"], out["valid"], out["data"])
         with _scope(POST):
-            out = post(res["k"], res["valid"], res["data"])
+            out = post(rk, rv, rd)
         return (
             out,
             jax.lax.psum(overflow, ctx.axis),
             jax.lax.pmax(fill, ctx.axis),
         )
 
+    # each distinct array enters the shard_map once, so a key that is also a
+    # leaf of the data stays one operand of the sorts inside
+    leaves, treedef = jax.tree.flatten((keys, valid, data))
+    uniq, index = _distinct(leaves)
     fn = compat.shard_map(
         f,
         mesh=ctx.mesh,
-        in_specs=(P(ctx.axis), P(ctx.axis), P(ctx.axis)),
+        in_specs=(P(ctx.axis),) * len(uniq),
         out_specs=(P(ctx.axis), P(), P()),
     )
-    return fn(keys, valid, data)
+    return fn(*uniq)
 
 
 def hash_stage(ctx: IContext, keys, valid, data, C: int, post=None, route=None):

@@ -25,7 +25,8 @@ The ``ShuffleManager`` closes that gap three ways:
    records the outcome.
 
 Telemetry lives in ``stats`` (exchanges, overflow/fan-out retries, deferred
-checks, capacity-memory hits, wide-plan compiles, bytes moved) — surfaced via
+checks, capacity-memory hits, wide-plan compiles, leaves a sort stage still
+gathers, bytes moved) — surfaced via
 ``worker.shuffle_stats()`` and the ``== shuffle ==`` section of
 ``df.explain()``.
 """
@@ -189,6 +190,7 @@ class ShuffleManager:
             "wide_plan_hits": 0,
             "wide_plan_misses": 0,     # wide-stage compiles
             "wide_plan_evictions": 0,
+            "sort_gathers": 0,         # leaves gathered after a sort, per plan built
             "bytes_moved": 0,          # exchanged-buffer bytes (estimate)
             "group_reshards": 0,       # blocks moved onto a different communicator
         })
@@ -430,6 +432,9 @@ class ShuffleManager:
         key = (kind, C, ascending, fn_token(key_fn), _block_aval(b), ctx.mesh)
 
         def builder():
+            # leaves that cannot ride in the stage's sort take a gather
+            self._bump("sort_gathers", sh.sort_gathers(b.data))
+
             def run(data, valid):
                 keys = jax.vmap(key_fn)(data)
                 if not ascending:
@@ -617,7 +622,7 @@ class ShuffleManager:
             f"capacity_memory: hits={s['capacity_memory_hits']} "
             f"misses={s['capacity_memory_misses']} entries={len(self._capacity)}\n"
             f"wide plans: compiled={s['wide_plan_misses']} hits={s['wide_plan_hits']} "
-            f"evictions={s['wide_plan_evictions']} bytes_moved={s['bytes_moved']} "
-            f"group_reshards={s['group_reshards']}\n"
+            f"sort_gathers={s['sort_gathers']} evictions={s['wide_plan_evictions']} "
+            f"bytes_moved={s['bytes_moved']} group_reshards={s['group_reshards']}\n"
             f"kernels: {self.kernels.describe()}"
         )

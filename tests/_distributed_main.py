@@ -36,6 +36,41 @@ def main():
     got = [int(x) for x in w.parallelize(vals).sort().collect()]
     check("psrs_sort_8shards", got == sorted(int(v) for v in vals))
 
+    # the PSRS stage's carrying sorts vs the argsort-plus-gather oracle: the
+    # valid rows, in order, bit for bit (a valid INT32_MAX key behind invalid
+    # rows included), and the leaves that still take a gather
+    from repro.core import shuffle as sh
+    from repro.core.partition import Block
+
+    n = 8 * 96
+    valid = rng.random(n) < 0.7
+    ints = rng.integers(-50, 50, n).astype(np.int32)
+    ints[rng.random(n) < 0.05] = np.iinfo(np.int32).max
+    valid[:8] = False
+    cases = {
+        "int32_desc": (jnp.asarray(ints), lambda r: r, False, 0),
+        "float32_asc": (jnp.asarray((ints / 4).astype(np.float32)), lambda r: r, True, 0),
+        "tree_2d_leaf": ({"key": jnp.asarray(ints % 7),
+                          "vec": jnp.asarray(rng.standard_normal((n, 3)), jnp.float32)},
+                         lambda r: r["key"], True, 1),
+        "max_key_after_invalid": (jnp.asarray(ints), lambda r: r, True, 0),
+    }
+    for name, (data, key_fn, ascending, gathers) in cases.items():
+        keys = jax.vmap(key_fn)(data)
+        keys = keys if ascending else -keys
+        order = jnp.argsort(jnp.where(valid, keys, sh._sentinel(keys.dtype)), stable=True)
+        keep = np.asarray(valid)[np.asarray(order)]
+        want = jax.tree.map(lambda x: np.asarray(x[order])[keep], data)
+        before = w.shuffle.stats["sort_gathers"]
+        out = w.shuffle.sort(("carry", name), Block(data, jnp.asarray(valid)),
+                             key_fn, ascending)
+        got_v = np.asarray(out.valid)
+        got_d = jax.tree.map(lambda x: np.asarray(x)[got_v], out.data)
+        check(f"sort_carry_8shards_{name}",
+              all(g.tobytes() == x.tobytes() for g, x in
+                  zip(jax.tree.leaves(got_d), jax.tree.leaves(want)))
+              and w.shuffle.stats["sort_gathers"] - before == gathers)
+
     kv = w.parallelize(vals).map(lambda x: {"key": x % 13, "value": jnp.int32(1)})
     counts = {int(np.asarray(r["key"])): int(np.asarray(r["value"]))
               for r in kv.reduce_by_key(lambda a, b: a + b, 0).collect()}

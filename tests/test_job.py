@@ -2,9 +2,11 @@
 future facades, cross-worker job DAGs (dataflow + native + importData),
 async overlap of independent branches, native nodes as lineage citizens,
 call_partitions lineage repair, and early-exit take."""
+import gc
 import time
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -166,6 +168,31 @@ def test_hybrid_job_is_one_dag_and_matches_eager(cluster):
     assert "call:double_native" in back.explain()
     # eager run of the same lineage agrees (facade path)
     assert sorted(int(x) for x in back.collect()) == exp
+
+
+def test_released_job_frees_its_blocks_without_the_cyclic_collector(cluster):
+    # a resolved task hands off its dependents: no deps <-> dependents cycle
+    # keeps a finished job's blocks on the device until gc runs
+    @ignis_export("triple_native")
+    def triple_native(ctx, data=None, valid=None):
+        return data * jnp.int32(3), valid
+
+    wd, ws = IWorker(cluster, "python"), IWorker(cluster, "spmd")
+    base = wd.parallelize(np.arange(64, dtype=np.int32))
+    gc.collect()
+    gc.disable()
+    try:
+        before = len(jax.live_arrays())
+        for _ in range(3):
+            tripled = ws.call("triple_native", ws.import_data(base.map(lambda x: x + 1)))
+            job = IJob("released")
+            back = wd.import_data(tripled).map(lambda x: x - 1)
+            assert back.count_async(job=job).result(60) == 64
+            job.release()
+            del job, tripled, back
+        assert len(jax.live_arrays()) == before
+    finally:
+        gc.enable()
 
 
 def test_shared_memo_evaluates_upstream_once(cluster):

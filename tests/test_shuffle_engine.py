@@ -13,6 +13,7 @@ import pytest
 
 from repro.core import ICluster, IProperties, IWorker
 from repro.core import shuffle as sh
+from repro.core.partition import Block
 
 
 @pytest.fixture
@@ -109,6 +110,66 @@ def test_join_fanout_overflow_retries_then_remembers(worker):
     s2 = worker.shuffle_stats()
     assert s2["fanout_retries"] == s1["fanout_retries"]
     assert s2["wide_plan_misses"] == s1["wide_plan_misses"]
+
+
+# ---------------------------------------------------------------------------
+# the sort stage's carrying sort vs the argsort-plus-gather oracle
+# ---------------------------------------------------------------------------
+
+
+def _oracle_valid_rows(data, valid, key_fn, ascending):
+    """The valid rows in the order ``argsort(where(valid, keys, sentinel))``
+    followed by one gather per leaf gives them."""
+    keys = jax.vmap(key_fn)(data)
+    if not ascending:
+        keys = -keys
+    order = jnp.argsort(jnp.where(valid, keys, sh._sentinel(keys.dtype)), stable=True)
+    keep = np.asarray(valid[order])
+    return jax.tree.map(lambda x: np.asarray(x[order])[keep], data)
+
+
+def _sort_case(case, n=96):
+    rng = np.random.default_rng(7)
+    valid = rng.random(n) < 0.7
+    ints = rng.integers(-50, 50, n).astype(np.int32)
+    if case == "max_key_after_invalid":
+        top = np.iinfo(np.int32).max
+        valid[:8] = False
+        ints[8:12] = top  # valid sentinel-valued keys behind invalid rows
+        ints[rng.random(n) < 0.1] = top
+        return ints, valid, lambda r: r, True
+    if case.startswith("float32"):
+        f = (ints / 4).astype(np.float32)
+        f[:4] = [0.0, -0.0, np.inf, -np.inf]
+        return f, valid, lambda r: r, case.endswith("asc")
+    if case.startswith("int32"):
+        return ints, valid, lambda r: r, case.endswith("asc")
+    if case == "kv":
+        return {"key": ints % 7, "value": ints}, valid, lambda r: r["key"], True
+    assert case == "tree_2d_leaf"
+    vec = rng.standard_normal((n, 3)).astype(np.float32)
+    return {"key": ints % 7, "vec": vec}, valid, lambda r: r["key"], True
+
+
+@pytest.mark.parametrize("case,gathers", [
+    ("int32_asc", 0), ("int32_desc", 0), ("float32_asc", 0), ("float32_desc", 0),
+    ("kv", 0), ("tree_2d_leaf", 1), ("max_key_after_invalid", 0)])
+def test_sort_stage_matches_argsort_gather_oracle(worker, case, gathers):
+    data, valid, key_fn, ascending = _sort_case(case)
+    data = jax.tree.map(jnp.asarray, data)
+    valid = jnp.asarray(valid)
+    before = worker.shuffle.stats["sort_gathers"]
+    out = worker.shuffle.sort(("carry", case), Block(data, valid), key_fn, ascending)
+    got_valid = np.asarray(out.valid)
+    n_valid = int(np.asarray(valid).sum())
+    # every valid row sorts before every invalid row
+    assert got_valid[:n_valid].all() and not got_valid[n_valid:].any()
+    want = _oracle_valid_rows(data, valid, key_fn, ascending)
+    got = jax.tree.map(lambda x: np.asarray(x)[got_valid], out.data)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.tobytes() == w.tobytes()  # bit-identical, -0.0 and order included
+    assert worker.shuffle.stats["sort_gathers"] - before == gathers
+    assert f"sort_gathers={worker.shuffle.stats['sort_gathers']}" in worker.shuffle.summary()
 
 
 # ---------------------------------------------------------------------------

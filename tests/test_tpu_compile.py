@@ -6,7 +6,8 @@ path's Pallas kernels at N = 2^20 for a described ``v5e:2x2`` and assert that
 Mosaic accepted them (``tpu_custom_call`` in the compiled HLO): interpret
 mode cannot show a tiling or lowering error, this can. One wide stage is
 compiled over the four described devices, so the exchange's ``all-to-all``
-and the routing kernel are checked together.
+and the routing kernel are checked together, and the one-chip sort stage is
+compiled to check that its payload rides in the sort (about 30 s here).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -101,6 +102,23 @@ def test_oracle_segmented_reduce_compiles_for_v5e(one_chip):
 
     jax.jit(f).lower(_arg((N,), jnp.int32, one_chip), _arg((N,), jnp.bool_, one_chip),
                      _arg((N,), jnp.int32, one_chip)).compile()
+
+
+def test_sort_stage_carries_payload_in_one_sort_for_v5e(topo, one_chip):
+    # the p = 1 sort stage of a {key, value} block: the payload rides in one
+    # multi-operand sort, and no leaf is gathered by an argsort's order
+    ctx = IContext(Mesh(np.asarray(topo.devices[:1]), ("data",)), "data")
+    assert ctx.executors == 1
+
+    def f(keys, valid, values):
+        return sh.sort_stage(ctx, keys, valid, {"key": keys, "value": values}, N)
+
+    text = jax.jit(f).lower(
+        _arg((N,), jnp.int32, one_chip), _arg((N,), jnp.bool_, one_chip),
+        _arg((N,), jnp.int32, one_chip)).compile().as_text()
+    sorts = re.findall(r" sort\(([^)]*)\)", text)  # each sort's operands
+    assert len(sorts) == 1 and sorts[0].count("%") > 1, sorts
+    assert " gather(" not in text
 
 
 def test_hash_stage_compiles_on_four_v5e_chips(topo):
