@@ -83,42 +83,97 @@ def _distinct(leaves):
     return uniq, [first[id(x)] for x in leaves]
 
 
-def sort_gathers(data) -> int:
-    """Leaves of a block's ``data`` that each local sort of ``sort_stage``
-    still gathers after the sort: those that cannot ride in it."""
-    return sum(not _rides(x) for x in _distinct(jax.tree.leaves(data))[0])
+def key_leaves(keys) -> tuple:
+    """A sort key as the tuple of its 1-D leaves, compared lexicographically:
+    a tuple key is its own leaves, any other key one leaf."""
+    if not isinstance(keys, tuple):
+        return (keys,)
+    if not keys or any(getattr(k, "ndim", None) != 1 for k in keys):
+        raise TypeError("a tuple sort key needs one or more leaves with one "
+                        "value per row")
+    return keys
+
+
+def row_bytes(x) -> int:
+    """Bytes of one row of the array ``x``."""
+    return math.prod(x.shape[1:]) * x.dtype.itemsize
+
+
+def _layout(ks, leaves):
+    """How a local sort that compares ``(~valid, *ks)`` moves ``leaves``,
+    each distinct array once. ``slot[id(x)]`` is the sort operand that
+    carries leaf ``x`` (a key leaf's own, or one of ``riders`` after the
+    keys), or ``-1 - j`` for the j-th of ``gathered``: the leaves with
+    trailing dimensions, which cannot ride in the sort and are gathered
+    after it by its order."""
+    slot = {}
+    for i, k in enumerate(ks, 1):
+        slot.setdefault(id(k), i)
+    riders, gathered = [], []
+    for x in leaves:
+        if id(x) in slot:
+            continue
+        if _rides(x):
+            slot[id(x)] = 1 + len(ks) + len(riders)
+            riders.append(x)
+        else:
+            slot[id(x)] = -1 - len(gathered)
+            gathered.append(x)
+    return slot, riders, gathered
+
+
+def sort_traffic(keys, data) -> dict:
+    """What each local sort of ``sort_stage`` compares and moves when it
+    sorts ``data`` by ``keys`` (arrays or tracers, one row each): the key
+    leaves, the leaves gathered after the sort, and the bytes a row of the
+    keys and the data (each distinct array once) and of those gathered
+    leaves."""
+    ks = key_leaves(keys)
+    _, riders, gathered = _layout(ks, jax.tree.leaves(data))
+    moved = [*_distinct(ks)[0], *riders, *gathered]
+    return dict(key_leaves=len(ks), gathers=len(gathered),
+                row_bytes=sum(map(row_bytes, moved)),
+                gather_row_bytes=sum(map(row_bytes, gathered)))
 
 
 def _sort_carry(keys, valid, *trees):
     """``(keys, valid, *trees)`` with their rows in one stable order: valid
-    rows first, by key, then the invalid rows.
+    rows first, by key, then the invalid rows. ``keys`` is one array or a
+    tuple of 1-D leaves, compared lexicographically, each in its dtype's
+    order.
 
-    One ``lax.sort`` compares ``(~valid, keys)`` lexicographically and
-    carries every 1-D leaf as an operand, each distinct array once (the keys
-    are often a leaf of the data); the sorted ``valid`` is the negated first
-    key. A leaf with trailing dimensions cannot be an operand: an iota rides
-    instead and those leaves are gathered by it. The valid rows come out in
-    the order ``_permute(_argsort(where(valid, keys, _sentinel)), ...)``
-    gives them, and a valid key equal to the sentinel still sorts before
-    every invalid row. With no payload beyond the two keys, rows that
-    compare equal are equal bit for bit, so the sort need not be stable
-    (XLA:TPU carries one more operand, an iota, for a stable sort)."""
+    One ``lax.sort`` compares ``(~valid, *key_leaves)`` lexicographically
+    and carries every other 1-D leaf as an operand, each distinct array once
+    (the keys are often leaves of the data); the sorted ``valid`` is the
+    negated first key. A leaf with trailing dimensions cannot be an operand:
+    an iota rides instead and those leaves are gathered by it (``_layout``).
+    The valid rows come out in the order ``_permute(_argsort(where(valid,
+    keys, _sentinel)), ...)`` gives them, and a valid key equal to the
+    sentinel still sorts before every invalid row. With no payload beyond
+    the keys, rows that compare equal are equal bit for bit, so the sort
+    need not be stable (XLA:TPU carries one more operand, an iota, for a
+    stable sort)."""
+    ks = key_leaves(keys)
     leaves, treedef = jax.tree.flatten(trees)
-    uniq, index = _distinct([valid, keys, *leaves])
-    rest = range(2, len(uniq))
-    carried = [1] + [i for i in rest if _rides(uniq[i])]
-    gathered = [i for i in rest if not _rides(uniq[i])]
+    slot, riders, gathered = _layout(ks, leaves)
+    operands = [~valid, *ks, *riders]
+    num_keys = 1 + len(ks)
     with _scope(ARGSORT):
-        operands = [~valid] + [uniq[i] for i in carried]
         if gathered:
-            operands.append(jnp.arange(keys.shape[0], dtype=jnp.int32))
-        out = jax.lax.sort(operands, num_keys=2, is_stable=len(operands) > 2)
-    got = {0: ~out[0], **{i: out[j] for j, i in enumerate(carried, 1)}}
+            operands.append(jnp.arange(valid.shape[0], dtype=jnp.int32))
+        out = list(jax.lax.sort(operands, num_keys=num_keys,
+                                is_stable=len(operands) > num_keys))
+    out[0] = ~out[0]
     if gathered:
         with _scope(PERMUTE):
-            got.update({i: uniq[i][out[-1]] for i in gathered})
-    res = [got[i] for i in index]
-    return (res[1], res[0], *jax.tree.unflatten(treedef, res[2:]))
+            moved = [x[out[-1]] for x in gathered]
+
+    def get(x):
+        i = slot[id(x)]
+        return out[i] if i >= 0 else moved[-1 - i]
+
+    sk = tuple(out[1:num_keys]) if isinstance(keys, tuple) else out[1]
+    return (sk, out[0], *jax.tree.unflatten(treedef, [get(x) for x in leaves]))
 
 
 def _sentinel(dtype):
@@ -267,6 +322,30 @@ def _passthrough(k, v, d):
     return d, v
 
 
+def _lt_eq(a, b):
+    """``a < b`` and ``a == b`` in ``lax.sort``'s order: for floats, -0
+    equals 0 and NaN equals NaN and sorts after every other value."""
+    lt, eq = a < b, a == b
+    if jnp.issubdtype(a.dtype, jnp.floating):
+        na, nb = jnp.isnan(a), jnp.isnan(b)
+        lt, eq = lt | (nb & ~na), eq | (na & nb)
+    return lt, eq
+
+
+def _route(samples, ks, p: int):
+    """Destination of each row from the (p·p,) regular samples of each key
+    leaf: the samples sorted lexicographically, the middle sample of
+    quantile i's group is global pivot i, and a row goes to the number of
+    pivot tuples at or below its key tuple, compared in the sort's order so
+    that destinations never decrease along the sorted rows."""
+    samples = jax.lax.sort(samples, num_keys=len(ks))
+    le = jnp.ones((p - 1, ks[0].shape[0]), bool)  # pivot <= row, (p-1, n)
+    for x, k in zip(reversed(samples), reversed(ks)):
+        lt, eq = _lt_eq(x[p + p // 2 - 1 :: p][: p - 1, None], k)
+        le = lt | (eq & le)
+    return le.sum(0, dtype=jnp.int32)
+
+
 def sort_stage(ctx: IContext, keys, valid, data, C: int, post=None):
     """One fused wide sort stage, no host syncs.
 
@@ -287,8 +366,7 @@ def sort_stage(ctx: IContext, keys, valid, data, C: int, post=None):
     def f(*arrays):
         k, v, d = jax.tree.unflatten(treedef, [arrays[i] for i in index])
         korig, vs, ds = _sort_carry(k, v, d)
-        big = _sentinel(k.dtype)
-        ks = jnp.where(vs, korig, big)
+        ks = tuple(jnp.where(vs, x, _sentinel(x.dtype)) for x in key_leaves(korig))
         with _scope(EXCHANGE):
             # regular sampling: the valid rows (which sort first) at quantiles
             # 0, 1/p, …, (p-1)/p — sampling the padding's sentinels would drag
@@ -297,12 +375,10 @@ def sort_stage(ctx: IContext, keys, valid, data, C: int, post=None):
             q, r = jnp.divmod(n_valid, p)
             j = jnp.arange(p, dtype=jnp.int32)
             idx = j * q + (j * r) // p  # j * n_valid // p, no overflow
-            samples = ks[idx]
-            all_samples = jax.lax.all_gather(samples, ctx.axis, tiled=True)  # (p·p,)
-            # the middle sample of quantile i's group is global pivot i
-            pivots = jnp.sort(all_samples)[p + p // 2 - 1 :: p][: p - 1]
-            dest = jnp.searchsorted(pivots, ks, side="right").astype(jnp.int32)
-        # the invalid rows, which sort after every valid row, stay home
+            samples = [jax.lax.all_gather(x[idx], ctx.axis, tiled=True) for x in ks]
+            # the invalid rows, which sort after every valid row, stay home:
+            # routed past the last executor, they keep ``dest`` in order
+            dest = jnp.where(vs, _route(samples, ks, p), p)
         payload = {"k": korig, "valid": vs, "data": ds}
         out, overflow, fill = _pack_sorted(dest, payload, n_valid, ctx.axis, p, C)
         with _scope(MERGE):

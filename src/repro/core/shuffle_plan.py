@@ -137,13 +137,22 @@ def _static_token(x):
         return (treedef, tuple(leaf(l) for l in leaves))
 
 
+def _descending(k):
+    """An order-reversing map that is exact for every value of ``k``'s
+    dtype: bitwise not for integers and bools, negation for floats."""
+    return -k if jnp.issubdtype(k.dtype, jnp.floating) else ~k
+
+
+def _one_key(op: str, keys):
+    """Refuse a tuple key, which only ``sort_by`` compares."""
+    if isinstance(keys, tuple):
+        raise TypeError(f"{op}: a tuple key is supported by sort_by only; "
+                        f"give {op} one key array per row")
+
+
 def _row_bytes(b: Block, key_bytes: int = 8) -> int:
     """Approximate bytes per exchanged row (payload leaves + key + validity)."""
-    per = sum(
-        int(np.prod(l.shape[1:], dtype=np.int64)) * l.dtype.itemsize
-        for l in jax.tree.leaves(b.data)
-    )
-    return per + key_bytes + 1
+    return sum(sh.row_bytes(l) for l in jax.tree.leaves(b.data)) + key_bytes + 1
 
 
 class ShuffleManager:
@@ -171,7 +180,7 @@ class ShuffleManager:
         self._fanout: "OrderedDict[tuple, int]" = OrderedDict()
         self._kernel_notes: "OrderedDict[object, str]" = OrderedDict()
         self._op_memo: "OrderedDict[tuple, Optional[str]]" = OrderedDict()
-        self._plans: "OrderedDict[tuple, Callable]" = OrderedDict()
+        self._plans: "OrderedDict[tuple, tuple]" = OrderedDict()
         # gang-scheduled tasks on disjoint groups share this manager from
         # several threads; LRU get+move / insert+evict, the capacity/fanout
         # memories, and the stats counters (CI-gated by check_bench.py —
@@ -191,6 +200,9 @@ class ShuffleManager:
             "wide_plan_misses": 0,     # wide-stage compiles
             "wide_plan_evictions": 0,
             "sort_gathers": 0,         # leaves gathered after a sort, per plan built
+            "sort_key_leaves": 0,      # key leaves a sort compares, per plan built
+            "sort_bytes": 0,           # key and data bytes through the local sorts
+            "sort_gather_bytes": 0,    # of those, bytes gathered after a sort
             "bytes_moved": 0,          # exchanged-buffer bytes (estimate)
             "group_reshards": 0,       # blocks moved onto a different communicator
         })
@@ -252,21 +264,25 @@ class ShuffleManager:
     # ------------------------------------------------------------------
     # wide-plan cache (compiled stage kernels; analogue of DESIGN.md §5)
     # ------------------------------------------------------------------
-    def _plan(self, key: tuple, builder: Callable[[], Callable]):
+    def _plan(self, key: tuple, builder: Callable[[], tuple]):
+        """The plan cached under ``key``: ``(jitted, note)``, built on a miss
+        from ``builder() -> (fn, note)``. The note stays with the plan, for
+        what its trace learns (a sort plan's ``sort_traffic``)."""
         with self._plan_lock:
-            fn = self._plans.get(key)
-            if fn is not None:
+            hit = self._plans.get(key)
+            if hit is not None:
                 self._plans.move_to_end(key)
                 self.stats["wide_plan_hits"] += 1
-                return fn
+                return hit
             self.stats["wide_plan_misses"] += 1
-        fn = jax.jit(builder())
+        fn, note = builder()
+        fn = jax.jit(fn)
         with self._plan_lock:
-            self._plans[key] = fn
+            self._plans[key] = fn, note
             while len(self._plans) > self.plan_cache_size:
                 self._plans.popitem(last=False)
                 self.stats["wide_plan_evictions"] += 1
-        return first_call("wide", fn)
+        return first_call("wide", fn), note
 
     def _account(self, b: Block, C: int):
         p = self.p
@@ -432,23 +448,36 @@ class ShuffleManager:
         key = (kind, C, ascending, fn_token(key_fn), _block_aval(b), ctx.mesh)
 
         def builder():
-            # leaves that cannot ride in the stage's sort take a gather
-            self._bump("sort_gathers", sh.sort_gathers(b.data))
+            traffic = {}
 
             def run(data, valid):
                 keys = jax.vmap(key_fn)(data)
+                if kind[0] != "sort":
+                    _one_key(kind[0], keys)
                 if not ascending:
-                    keys = -keys
+                    keys = jax.tree.map(_descending, keys)
+                # filled once, when the plan is traced
+                traffic.update(sh.sort_traffic(keys, data))
+                self._bump("sort_key_leaves", traffic["key_leaves"])
+                self._bump("sort_gathers", traffic["gathers"])
                 return sh.sort_stage(ctx, keys, valid, data, C, post)
 
-            return run
+            return run, traffic
 
-        fn = self._plan(key, builder)
+        fn, traffic = self._plan(key, builder)
         self._account(b, C)
         faults.check("shuffle.stage", kind=kind[0], p=self.p)
         if kernel is not None:
             faults.check("kernel.stage", kind=kind[0], kernel=kernel, p=self.p)
-        return fn(b.data, b.valid)
+        out = fn(b.data, b.valid)
+        # every row goes through the first local sort, and at p > 1 the
+        # p*C rows each shard receives through the merge's sort
+        p = self.p
+        rows = b.capacity + (p * p * C if p > 1 else 0)
+        with self._plan_lock:
+            self.stats["sort_bytes"] += rows * traffic["row_bytes"]
+            self.stats["sort_gather_bytes"] += rows * traffic["gather_row_bytes"]
+        return out
 
     def sort(self, sig, b: Block, key_fn, ascending: bool = True) -> Block:
         return self._sorted(sig, b, key_fn, ascending, None, ("sort",))
@@ -517,9 +546,9 @@ class ShuffleManager:
                 keys = jax.vmap(key_fn)(data)
                 return sh.hash_stage(ctx, keys, valid, data, C, route=route)
 
-            return run
+            return run, None
 
-        fn = self._plan(key, builder)
+        fn, _ = self._plan(key, builder)
         self._account(b, C)
         faults.check("shuffle.stage", kind="partitionBy", p=self.p)
         if sel is not None:
@@ -531,6 +560,8 @@ class ShuffleManager:
     # join (both-side exchange + bounded-fan-out merge, one stage)
     # ------------------------------------------------------------------
     def join(self, sig, lb: Block, rb: Block, max_matches: int) -> Block:
+        _one_key("join", lb.data["key"])
+        _one_key("join", rb.data["key"])
         lb, rb = self._placed(lb), self._placed(rb)
         p = self.p
         nl, nr = lb.capacity, rb.capacity
@@ -560,9 +591,9 @@ class ShuffleManager:
                                          rd["key"], rv, rd["value"], Cl, Cr, M,
                                          route_l=route_l, route_r=route_r)
 
-                return run
+                return run, None
 
-            fn = self._plan(key, builder)
+            fn, _ = self._plan(key, builder)
             if p > 1:
                 self._account(lb, Cl)
                 self._account(rb, Cr)
@@ -624,5 +655,7 @@ class ShuffleManager:
             f"wide plans: compiled={s['wide_plan_misses']} hits={s['wide_plan_hits']} "
             f"sort_gathers={s['sort_gathers']} evictions={s['wide_plan_evictions']} "
             f"bytes_moved={s['bytes_moved']} group_reshards={s['group_reshards']}\n"
+            f"sorts: key_leaves={s['sort_key_leaves']} bytes={s['sort_bytes']} "
+            f"gather_bytes={s['sort_gather_bytes']}\n"
             f"kernels: {self.kernels.describe()}"
         )

@@ -71,6 +71,59 @@ def main():
                   zip(jax.tree.leaves(got_d), jax.tree.leaves(want)))
               and w.shuffle.stats["sort_gathers"] - before == gathers)
 
+    # tuple keys on 8 shards (PSRS samples, pivots and routing compared
+    # lexicographically) against the NumPy reference of the CPU tests: ties
+    # in the leading leaves, each dtype's largest value, invalid rows, a
+    # 2-D and a 1-D payload leaf, both orders
+    from test_shuffle_engine import TUPLE_KEYS, _records, check_tuple_sort
+
+    def tuple_sort_ok(worker, name, leaves, ascending, n):
+        data, valid = _records(n=n, seed=leaves)
+        out = worker.shuffle.sort(("tuple", name), Block(
+            jax.tree.map(jnp.asarray, data), jnp.asarray(valid)),
+            TUPLE_KEYS[leaves], ascending)
+        try:
+            check_tuple_sort(data, valid, out.data, out.valid, leaves, ascending)
+        except AssertionError:
+            return False
+        return True
+
+    for leaves in (2, 3):
+        for ascending in (True, False):
+            check(f"tuple_sort_8shards_{leaves}_{'asc' if ascending else 'desc'}",
+                  tuple_sort_ok(w, f"{leaves}{ascending}", leaves, ascending, 8 * 96))
+    for name, x in {"int32_min": np.asarray([3, -2**31, 0, 2**31 - 1, -1] * 40, np.int32),
+                    "uint32": np.asarray([0, 2**32 - 1, 5, 2**31] * 50, np.uint32)}.items():
+        got = [np.asarray(v).item() for v in w.parallelize(x).sort(ascending=False).collect()]
+        check(f"descending_8shards_{name}", got == sorted(x.tolist(), reverse=True))
+
+    # float keys in the sort's order on 8 shards: -0 equals 0, and NaN sorts
+    # after every other value in both orders (descending negates the key),
+    # with NaN rows on every executor and NaN pivots; alone, and as the first
+    # of two leaves with invalid rows, which must stay behind the NaN rows
+    f = rng.integers(-20, 20, n).astype(np.float32) / 4
+    f[rng.random(n) < 0.3] = np.nan
+    f[rng.random(n) < 0.1] = -0.0
+    tag = np.arange(n, dtype=np.int32) % 5
+    valid = rng.random(n) < 0.8
+    for ascending in (True, False):
+        word = "asc" if ascending else "desc"
+        got = np.asarray([np.asarray(v).item()
+                          for v in w.parallelize(f).sort(ascending=ascending).collect()])
+        want = np.sort(f) if ascending else -np.sort(-f)
+        check(f"float_nan_sort_8shards_{word}", np.array_equal(got, want, equal_nan=True))
+        out = w.shuffle.sort(("float_nan", word), Block(
+            {"f": jnp.asarray(f), "tag": jnp.asarray(tag)}, jnp.asarray(valid)),
+            lambda r: (r["f"], r["tag"]), ascending)
+        got_v = np.asarray(out.valid)
+        got_f, got_t = (np.asarray(out.data[k])[got_v] for k in ("f", "tag"))
+        sign = 1 if ascending else -1
+        vf, vt = f[valid], tag[valid]
+        order = np.lexsort((sign * vt, sign * vf))  # NaN last, as np.sort
+        check(f"float_nan_tuple_sort_8shards_{word}",
+              np.array_equal(got_f, vf[order], equal_nan=True)
+              and np.array_equal(got_t, vt[order]))
+
     kv = w.parallelize(vals).map(lambda x: {"key": x % 13, "value": jnp.int32(1)})
     counts = {int(np.asarray(r["key"])): int(np.asarray(r["value"]))
               for r in kv.reduce_by_key(lambda a, b: a + b, 0).collect()}
@@ -111,6 +164,12 @@ def main():
           st2["overflow_retries"] == st1["overflow_retries"]
           and st2["wide_plan_misses"] == st1["wide_plan_misses"]
           and st2["capacity_memory_hits"] > st1["capacity_memory_hits"])
+
+    # a tuple-key sort whose buckets overflow: the retry keeps every row
+    retries = wt.shuffle_stats()["overflow_retries"]
+    check("overflow_tuple_sort_correct", tuple_sort_ok(wt, "overflow", 3, True, 8 * 64))
+    check("overflow_tuple_sort_retried", wt.shuffle_stats()["overflow_retries"] > retries)
+    st2 = wt.shuffle_stats()
 
     # hash-exchange overflow (partitionBy with 5-key skew at p=8, C≈1)
     pb = wt.parallelize(vals_t).map(

@@ -6,6 +6,8 @@ Exchange-capacity overflow needs p > 1 and is covered in the 8-device
 subprocess suite (tests/_distributed_main.py); here we cover everything
 observable at p = 1, including join fan-out overflow (which is p-independent).
 """
+import re
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -170,6 +172,165 @@ def test_sort_stage_matches_argsort_gather_oracle(worker, case, gathers):
         assert g.tobytes() == w.tobytes()  # bit-identical, -0.0 and order included
     assert worker.shuffle.stats["sort_gathers"] - before == gathers
     assert f"sort_gathers={worker.shuffle.stats['sort_gathers']}" in worker.shuffle.summary()
+
+
+def _records(n=160, seed=11):
+    """Rows with a 3-leaf key (uint32, int32, uint16) that ties in its
+    leading leaves and holds each dtype's largest value (the sort's
+    sentinel), a 2-D and a 1-D payload leaf, and invalid rows."""
+    rng = np.random.default_rng(seed)
+    a = rng.integers(0, 3, n).astype(np.uint32)
+    b = rng.integers(-2, 2, n).astype(np.int32)
+    c = rng.integers(0, 4, n).astype(np.uint16)
+    a[rng.random(n) < 0.15] = np.iinfo(np.uint32).max
+    b[rng.random(n) < 0.15] = np.iinfo(np.int32).max
+    b[rng.random(n) < 0.1] = np.iinfo(np.int32).min
+    c[rng.random(n) < 0.15] = np.iinfo(np.uint16).max
+    data = {"a": a, "b": b, "c": c, "row": np.arange(n, dtype=np.int32),
+            "pay": rng.integers(0, 2**31, (n, 5)).astype(np.uint32)}
+    valid = rng.random(n) < 0.8
+    valid[:6] = False
+    return data, valid
+
+
+TUPLE_KEYS = {2: lambda r: (r["a"], r["b"]), 3: lambda r: (r["a"], r["b"], r["c"])}
+
+
+def check_tuple_sort(data, valid, got_data, got_valid, leaves, ascending):
+    """NumPy reference of a tuple-key sort: every valid row once, its
+    payload intact, and the valid rows, read in order, in lexicographic
+    order of their keys (each leaf in its dtype's order)."""
+    got_valid = np.asarray(got_valid)
+    got = {k: np.asarray(x)[got_valid] for k, x in got_data.items()}
+    names = ["a", "b", "c"][:leaves]
+    rows = np.nonzero(valid)[0]
+    order = rows[np.lexsort([data[k][rows] for k in reversed(names)])]
+    if not ascending:
+        order = order[::-1]
+    for k in names:  # the key sequence, in order
+        assert np.array_equal(got[k], data[k][order]), k
+    assert sorted(got["row"].tolist()) == rows.tolist()  # each row once
+    for k in data:  # every leaf of each row travelled with its row
+        assert np.array_equal(got[k], data[k][got["row"]]), k
+
+
+@pytest.mark.parametrize("ascending", [True, False])
+@pytest.mark.parametrize("leaves", [2, 3])
+def test_sort_by_tuple_key_matches_numpy_reference(worker, leaves, ascending):
+    data, valid = _records()
+    b = Block(jax.tree.map(jnp.asarray, data), jnp.asarray(valid))
+    out = worker.shuffle.sort(("tuple", leaves), b, TUPLE_KEYS[leaves], ascending)
+    n_valid = int(valid.sum())  # on one executor, valid rows come first
+    assert np.asarray(out.valid)[:n_valid].all() and not np.asarray(out.valid)[n_valid:].any()
+    check_tuple_sort(data, valid, out.data, out.valid, leaves, ascending)
+
+
+def test_sort_by_tuple_key_through_the_dataframe(worker):
+    data, valid = _records()
+    keep = {k: v[valid] for k, v in data.items()}
+    keep["row"] = np.arange(int(valid.sum()), dtype=np.int32)
+    df = worker.parallelize(keep).sort_by(TUPLE_KEYS[3])
+    got = df.collect()
+    keys = [(int(r["a"]), int(r["b"]), int(r["c"])) for r in got]
+    assert keys == sorted(zip(*(keep[k].tolist() for k in "abc")))
+    for r in got:
+        assert np.array_equal(np.asarray(r["pay"]), keep["pay"][int(r["row"])])
+
+
+@pytest.mark.parametrize("case", ["int32_min", "uint32", "uint8", "bool"])
+def test_descending_order_is_exact_for_every_dtype(worker, case):
+    # a negated key wraps at INT32_MIN and misorders every unsigned key
+    rng = np.random.default_rng(3)
+    if case == "int32_min":
+        x = rng.integers(-5, 5, 64).astype(np.int32)
+        x[::7] = np.iinfo(np.int32).min
+        x[3::9] = np.iinfo(np.int32).max
+    elif case == "bool":
+        x = rng.random(64) < 0.5
+    else:
+        dt = np.dtype(case)
+        x = rng.integers(0, np.iinfo(dt).max, 64, endpoint=True).astype(dt)
+        x[::5] = 0
+        x[1::11] = np.iinfo(dt).max
+    got = [np.asarray(v).item() for v in worker.parallelize(x).sort(ascending=False).collect()]
+    assert got == sorted(x.tolist(), reverse=True)
+
+
+@pytest.mark.parametrize("ascending", [True, False])
+def test_float_keys_sort_nan_last_in_both_orders(worker, ascending):
+    f = np.asarray([1.5, np.nan, -0.0, 0.0, -2.0, np.nan, np.inf, -np.inf], np.float32)
+    got = np.asarray([np.asarray(v).item()
+                      for v in worker.parallelize(f).sort(ascending=ascending).collect()])
+    want = np.sort(f) if ascending else -np.sort(-f)
+    assert np.array_equal(got, want, equal_nan=True)
+
+
+@pytest.mark.parametrize("case", ["int32_min", "uint32"])
+def test_top_takes_the_largest_keys(worker, case):
+    x = np.arange(40, dtype=np.int32) - 20
+    if case == "int32_min":
+        x[5] = np.iinfo(np.int32).min
+    else:
+        x = (x.astype(np.int64) % 2**32).astype(np.uint32)  # negatives wrap high
+    got = [np.asarray(v).item() for v in worker.parallelize(x).top(4)]
+    assert got == sorted(x.tolist(), reverse=True)[:4]
+
+
+def test_sort_counters_count_key_leaves_and_bytes(worker):
+    data, valid = _records(n=64)
+    b = Block(jax.tree.map(jnp.asarray, data), jnp.asarray(valid))
+    before = dict(worker.shuffle.stats)
+    worker.shuffle.sort(("count", 3), b, TUPLE_KEYS[3])
+    worker.shuffle.sort(("count", 3), b, TUPLE_KEYS[3])  # a plan hit: leaves once
+    d = {k: worker.shuffle.stats[k] - before[k] for k in before}
+    # a, b, c ride as keys, row rides, pay (5 uint32 a row) is gathered
+    row = 4 + 4 + 2 + 4 + 20
+    assert d["sort_key_leaves"] == 3 and d["sort_gathers"] == 1
+    assert d["sort_bytes"] == 2 * 64 * row
+    assert d["sort_gather_bytes"] == 2 * 64 * 20
+    # descending: the keys are new arrays, each an operand beside its leaf
+    worker.shuffle.sort(("count", 1), b, lambda r: r["a"], False)
+    assert worker.shuffle.stats["sort_key_leaves"] - before["sort_key_leaves"] == 4
+    assert (worker.shuffle.stats["sort_bytes"] - before["sort_bytes"]
+            == 2 * 64 * row + 64 * (row + 4))
+    assert "sorts: key_leaves=4" in worker.shuffle.summary()
+
+
+def _sort_operands(text: str) -> list:
+    """The operand types of each ``stablehlo.sort`` in a lowered module."""
+    return [re.findall(r"tensor<\d+x(\w+)>", m)
+            for m in re.findall(r"stablehlo\.sort\"?\(.*?\n?.*?\}\) : \(([^)]*)\)",
+                                text, flags=re.S)]
+
+
+@pytest.mark.parametrize("case,operands,stable", [
+    ("is", ["i1", "i32"], "false"), ("wordcount", ["i1", "i32", "i32"], "true")])
+def test_scalar_key_stage_lowers_to_the_same_sort(worker, case, operands, stable):
+    # one scalar key: (~valid, key) and the 1-D payload, as before tuple keys
+    n = 128
+    keys = jnp.arange(n, dtype=jnp.int32)[::-1]
+    data = keys if case == "is" else {"key": keys, "value": keys % 3}
+    key_fn = (lambda r: r) if case == "is" else (lambda r: r["key"])
+    ctx = worker.context
+
+    def stage(d, v):
+        return sh.sort_stage(ctx, jax.vmap(key_fn)(d), v, d, n)
+
+    text = jax.jit(stage).lower(data, jnp.ones(n, bool)).as_text()
+    assert _sort_operands(text) == [operands]
+    assert f"is_stable = {stable}" in text
+
+
+@pytest.mark.parametrize("op", ["distinct", "reduceByKey", "groupByKey", "join"])
+def test_tuple_key_refused_by_other_ops(worker, op):
+    kv = worker.parallelize(np.arange(12, dtype=np.int32)).map(
+        lambda x: {"key": (x % 3, x % 2), "value": x})
+    frame = {"distinct": lambda: kv.distinct(lambda r: r["key"]),
+             "reduceByKey": lambda: kv.reduce_by_key(lambda a, b: a + b),
+             "groupByKey": lambda: kv.group_by_key(),
+             "join": lambda: kv.join(kv)}[op]()
+    with pytest.raises(TypeError, match=op):
+        frame.collect()
 
 
 # ---------------------------------------------------------------------------
