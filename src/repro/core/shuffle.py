@@ -565,15 +565,25 @@ def chunked_scan(comb, elems, chunk: int = SCAN_CHUNK):
         first, rest)
 
 
+def segment_tails(keys, valid):
+    """Last valid row of every equal-key run: the next row holds another
+    key or is invalid, or there is none. Computed from the keys, not from
+    the heads, so XLA:TPU fuses it into the output mask and keeps no
+    boundary array alive beside the scan."""
+    ends = (keys[1:] != keys[:-1]) | ~valid[1:]
+    return valid & jnp.concatenate([ends, jnp.ones((min(keys.shape[0], 1),), bool)])
+
+
 def segmented_reduce(keys, valid, values, fn, identity):
     """Reduce consecutive equal-key runs (keys must be sorted, invalid at
-    arbitrary positions). Returns (head_mask, reduced_values_at_heads).
+    arbitrary positions). Returns ``(tails, scanned)``: the inclusive
+    segmented scan, whose value at a segment's last row (``tails``) is the
+    segment's total; other valid rows hold a partial total and invalid
+    rows the identity. Keeping the last row needs no gather.
 
     fn: associative binary row fn (pytrees); identity: row pytree.
     """
-    n = keys.shape[0]
-    heads = segment_heads(keys, valid)
-    heads_ext = heads | ~valid
+    bounds = segment_heads(keys, valid) | ~valid
 
     vals = jax.tree.map(
         lambda x, i: jnp.where(
@@ -594,15 +604,8 @@ def segmented_reduce(keys, valid, values, fn, identity):
         )
         return (v, ha | hb)
 
-    scanned, _ = chunked_scan(comb, (vals, heads_ext))
-    # last row of each segment = (next head_ext) - 1
-    idx = jnp.arange(n)
-    head_pos = jnp.where(heads_ext, idx, n)
-    suff_min = chunked_scan(jnp.minimum, head_pos[::-1])[::-1]
-    nxt = jnp.concatenate([suff_min[1:], jnp.full((1,), n)])
-    last_pos = jnp.clip(jnp.where(nxt >= n, n - 1, nxt - 1), 0, n - 1)
-    out = jax.tree.map(lambda s: s[last_pos], scanned)
-    return heads, out
+    scanned, _ = chunked_scan(comb, (vals, bounds))
+    return segment_tails(keys, valid), scanned
 
 
 # ---------------------------------------------------------------------------
@@ -617,31 +620,33 @@ def heads_post(keys, valid, data):
 
 
 def make_reduce_post(fn, identity):
-    """reduceByKey: segmented reduce fused into the sort stage."""
+    """reduceByKey: segmented reduce fused into the sort stage. Each key's
+    row is its segment's last, which holds the total; the key there is the
+    head's, so the rows stay one per key and in key order."""
 
     def post(keys, valid, data):
-        heads, red = segmented_reduce(keys, valid, data["value"], fn, identity)
-        return {"key": data["key"], "value": red}, heads
+        tails, red = segmented_reduce(keys, valid, data["value"], fn, identity)
+        return {"key": data["key"], "value": red}, tails
 
     return post
 
 
 def make_reduce_post_kernel(op: str, identity, block: int, interpret: bool):
     """reduceByKey on the kernel tier (docs/kernels.md): the Pallas
-    segmented scan + prefix pass replaces ``segmented_reduce``, fused into
-    the same wide stage. Only built for values the registry recognized as
-    a single supported-dtype leaf with a builtin op — bit-identical to
-    ``make_reduce_post`` for associative-exact data."""
+    segmented scan replaces ``segmented_reduce``, fused into the same wide
+    stage, and keeps the same rows. Only built for values the registry
+    recognized as a single supported-dtype leaf with a builtin op —
+    bit-identical to ``make_reduce_post`` for associative-exact data."""
     from repro.kernels.segment_reduce.ops import segment_totals
 
     def post(keys, valid, data):
         leaves, treedef = jax.tree_util.tree_flatten(data["value"])
         ident = jax.tree_util.tree_leaves(identity)[0]
-        heads, red = segment_totals(keys, valid, leaves[0], op=op,
+        tails, red = segment_totals(keys, valid, leaves[0], op=op,
                                     identity=ident, block=block,
                                     interpret=interpret)
         value = jax.tree_util.tree_unflatten(treedef, [red])
-        return {"key": data["key"], "value": value}, heads
+        return {"key": data["key"], "value": value}, tails
 
     return post
 

@@ -8,7 +8,7 @@ ops.py (jit'd public wrapper, CPU auto-interpret), ref.py (pure-jnp oracle).
                     shows dominating the jnp baseline
   ssd_scan        — Mamba-2 SSD chunk scan (intra-chunk attention-like +
                     carried inter-chunk state); also hosts prefix_scan, the
-                    same carry pattern backing the shuffle prefix pass
+                    same carry pattern over a 1-D prefix scan
   segment_reduce  — sorted segmented reduction (reduceByKey/groupBy hot path
                     of the dataflow layer — the paper's TeraSort/K-Means side)
                     + segment_totals, the shuffle-stage ABI entry

@@ -2,17 +2,17 @@
 
 ``segment_reduce`` is the standalone inclusive-scan entry (kernel tests);
 ``segment_totals`` is the shuffle-stage ABI (docs/kernels.md): the drop-in
-kernel implementation of core/shuffle.segmented_reduce, combining the
-segment scan with the ssd-carry prefix pass for the last-row gather.
+kernel implementation of core/shuffle.segmented_reduce, which keeps each
+segment's total on its last row.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from repro.core.shuffle import segment_tails
 from repro.kernels.segment_reduce.ref import heads_of
 from repro.kernels.segment_reduce.segment_reduce import segment_reduce_fwd
-from repro.kernels.ssd_scan.ops import prefix_scan
 from repro.kernels.ssd_scan.prefix import op_identity
 
 
@@ -58,31 +58,24 @@ def segment_reduce(keys, valid, values, op: str = "sum", block: int = 8192,
 
 def segment_totals(keys, valid, values, op: str, identity, block: int = 8192,
                    interpret=None):
-    """Shuffle-stage ABI: per-segment totals broadcast to every row.
+    """Shuffle-stage ABI: the segmented scan, each total on its last row.
 
     Drop-in for core/shuffle.segmented_reduce with a builtin fn: invalid
     rows are masked to the *user* identity (the oracle's contract — the
     identity never enters a combine, invalid rows are their own
-    boundaries), the segment scan runs in the kernel, and the last-row
-    gather uses the prefix kernel's reverse cummin. Bit-identical to the
-    oracle for associative-exact data (integers; max/min on any dtype).
+    boundaries) and the segment scan runs in the kernel. The total is
+    exact at each kept row (``tails``, the last valid row of every
+    segment); other valid rows hold a partial total, invalid rows the
+    identity. No gather: the kept value is the scanned element itself.
+    Bit-identical to the oracle for associative-exact data (integers;
+    max/min on any dtype).
 
-    Returns (heads (N,) bool, totals (N, …) in values.dtype).
+    Returns (tails (N,) bool, scanned (N, …) in values.dtype).
     """
     interpret = _should_interpret() if interpret is None else interpret
-    n = keys.shape[0]
-    if n == 0:
+    if keys.shape[0] == 0:
         return jnp.zeros(0, bool), values
-    heads, scanned, squeeze = _scan(keys, valid, values, op, identity,
-                                    block, interpret)
-    hb = heads | ~valid
-    # last row of each segment = (next boundary) - 1, via the suffix-min
-    # prefix pass (core/shuffle.segmented_reduce's exact formula)
-    idx = jnp.arange(n)
-    head_pos = jnp.where(hb, idx, n).astype(jnp.int32)
-    suff_min = prefix_scan(head_pos, op="min", block=block,
-                           interpret=interpret, reverse=True)
-    nxt = jnp.concatenate([suff_min[1:], jnp.full((1,), n, jnp.int32)])
-    last_pos = jnp.clip(jnp.where(nxt >= n, n - 1, nxt - 1), 0, n - 1)
-    out = scanned[last_pos].astype(values.dtype)
-    return heads, (out[:, 0] if squeeze else out)
+    _, scanned, squeeze = _scan(keys, valid, values, op, identity, block,
+                                interpret)
+    out = scanned.astype(values.dtype)
+    return segment_tails(keys, valid), (out[:, 0] if squeeze else out)

@@ -1,7 +1,7 @@
 """Public SSD scan wrapper: CPU auto-interpret + ref-vjp backward.
 
-Also hosts ``prefix_scan`` — the SSD carry pattern applied to the shuffle
-engine's prefix pass (prefix.py, docs/kernels.md)."""
+Also hosts ``prefix_scan`` — the SSD carry pattern applied to a 1-D
+prefix scan (prefix.py, docs/kernels.md)."""
 from __future__ import annotations
 
 import functools
@@ -22,8 +22,8 @@ def prefix_scan(x, op: str = "sum", block: int = 8192, interpret=None,
                 reverse: bool = False):
     """Inclusive prefix scan (sum/max/min) over a 1-D array.
 
-    ``reverse=True`` scans from the tail (the suffix-min pass of
-    core/shuffle.segmented_reduce). Bool rides as i32 and is cast back.
+    ``reverse=True`` scans from the tail (a suffix scan). Bool rides as
+    i32 and is cast back.
     Bit-identical to ``prefix_scan_ref`` for integer dtypes (associative-
     exact ops — any association order agrees)."""
     interpret = _should_interpret() if interpret is None else interpret

@@ -3,9 +3,9 @@
 The SSD chunk scan's inter-chunk recurrence pattern applied to the shuffle
 engine's prefix pass: grid (n_blocks,) sequential over row tiles, a
 ``(1, 128)`` VMEM tile carries the running reduction across grid steps
-(exactly how ssd_scan.py carries its (P, N) state). Backs
-``segment_totals``' last-row gather (core/shuffle.segmented_reduce's
-``suff_min`` pass) — docs/kernels.md.
+(exactly how ssd_scan.py carries its (P, N) state). No wide stage calls
+it: ``segment_totals`` keeps each total on its segment's last row, so the
+reverse cummin it ran to find that row is gone — docs/kernels.md.
 
 Layout (shared with the segment and route kernels): a 1-D operand of N
 elements is padded and viewed lane-dense as ``(N/128, 128)`` in row-major
