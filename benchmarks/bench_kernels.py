@@ -4,9 +4,9 @@ repeat-run counter gate.
 
 Three row groups:
 
-* ``kern_*`` — each shuffle-tier kernel (prefix_scan / segment_totals /
-  bucket_route) timed under jit against its always-available jnp oracle,
-  in the mode the registry would actually pick for this backend
+* ``kern_*`` — the shuffle-tier kernel's stage entry (segment_totals)
+  timed under jit against its always-available jnp oracle, in the mode
+  the registry would actually pick for this backend
   (compiled on TPU, interpret elsewhere). The ratio is informational
   (no ``target=``): interpreted Pallas is EXPECTED to lose to the oracle
   on CPU — that asymmetry is exactly why auto mode never interprets.
@@ -37,10 +37,8 @@ import numpy as np
 from benchmarks.common import row, timeit
 from repro.core import ICluster, IProperties, IWorker
 from repro.core.shuffle import segmented_reduce
-from repro.kernels.moe_route import bucket_route, bucket_route_ref
 from repro.kernels.registry import compiled_backend
 from repro.kernels.segment_reduce import segment_totals
-from repro.kernels.ssd_scan import prefix_scan, prefix_scan_ref
 
 
 def _per_kernel_rows(n: int):
@@ -50,21 +48,13 @@ def _per_kernel_rows(n: int):
     x = jnp.asarray(rng.integers(-1000, 1000, n).astype(np.int32))
     keys = jnp.sort(jnp.asarray(rng.integers(0, 512, n).astype(np.int32)))
     valid = jnp.asarray(rng.random(n) < 0.9)
-    dest = jnp.asarray(rng.integers(0, 8, n).astype(np.int32))
-    cap = max(n // 4, 1)
 
     pairs = [
-        ("prefix_scan",
-         jax.jit(lambda v: prefix_scan(v, "sum", 512, interpret)),
-         jax.jit(lambda v: prefix_scan_ref(v)), x),
         ("segment_totals",
          jax.jit(lambda v: segment_totals(keys, valid, v, "sum",
                                           jnp.int32(0), 512, interpret)),
          jax.jit(lambda v: segmented_reduce(keys, valid, v,
                                             jnp.add, jnp.int32(0))), x),
-        ("bucket_route",
-         jax.jit(lambda v: bucket_route(v, 8, cap, 512, interpret)),
-         jax.jit(lambda v: bucket_route_ref(v, 8, cap)), dest),
     ]
     rows = []
     for name, kern, oracle, arg in pairs:

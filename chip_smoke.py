@@ -16,9 +16,8 @@ compares its result with a NumPy reference computed from ``--seed``:
   2^16 values, ``parallelize -> map -> reduce_by_key(sum)``, once with
   ``ignis.kernels=auto`` and once with ``off``. The auto run must ride
   ``segment_reduce[compiled]`` with no fallback, and both runs must agree
-  to the bit. With four chips a ``partition_by`` precedes the reduce, its
-  exchange must ride ``bucket_route[compiled]``, and only the auto run is
-  made.
+  to the bit. With four chips a ``partition_by`` precedes the reduce, and
+  only the auto run is made.
 * sort: 2^27 uniform int32 keys through ``sort()``, checked by count, sum
   and sum of squares (mod 2^32), min, max and adjacent-pair order, all
   reduced on the device.
@@ -240,12 +239,8 @@ def aggregation(rng, chips: int, n: int = AGG_RECORDS,
         if mode == "off":
             check(km["kernel_hits"] == 0, "kernels=off ran a kernel")
         else:
-            text = red.explain()
-            check(f"segment_reduce[{tier}]" in text,
+            check(f"segment_reduce[{tier}]" in red.explain(),
                   f"aggregation[{mode}] did not run segment_reduce[{tier}]")
-            if chips > 1:
-                check(f"bucket_route[{tier}]" in text,
-                      f"aggregation[{mode}] did not run bucket_route[{tier}]")
             check(km["kernel_fallbacks"] == 0,
                   f"aggregation[{mode}] fell back {km['kernel_fallbacks']} times")
         check(np.array_equal(np.asarray(totals), ref),

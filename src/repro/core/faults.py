@@ -24,10 +24,10 @@ Injection sites (threaded through the runtime):
                       partitionBy/join), ``p``
   ``shuffle.overflow``the capacity-overflow retry path: ``kind``
   ``kernel.stage``    a KERNEL-BACKED wide stage (``shuffle_plan.py``,
-                      docs/kernels.md) — fires only when the stage runs on
-                      the Pallas tier: ``kind``, ``kernel``
-                      (segment_reduce/bucket_route), ``p``. A task fault:
-                      the scheduler retries via lineage.
+                      docs/kernels.md) — fires only when a reduceByKey
+                      stage runs on the Pallas tier: ``kind``, ``kernel``
+                      (segment_reduce), ``p``. A task fault: the scheduler
+                      retries via lineage.
   ``kernel.capability``the kernel tier's per-node capability check
                       (``kernels/registry.py``): ``kernel``. NOT a task
                       fault — an injected failure degrades the node to the

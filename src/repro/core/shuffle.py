@@ -211,44 +211,43 @@ def capacity_for(factor: float, n_local: int, p: int) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _pack_exchange(dest, payload, axis, p, C, route=None):
-    """Inside shard_map: route rows to `dest` buckets with capacity C.
+def _pack(dest, payload, p, C):
+    """Rows packed into ``dest`` buckets of capacity C, on one shard.
 
-    dest: (n,) int32 in [0, p); payload: pytree of (n, …) leaves (must include
-    its own validity leaf). Returns (pytree of (p·C, …), overflow, max_fill).
-    Dropped rows (bucket overflow) are counted, not silently lost; max_fill is
-    the largest bucket demand observed — the capacity that *would* have fit,
-    independent of C, so one retry sized from it always succeeds.
+    dest: (n,) int32 in [0, p); payload: pytree of (n, …) leaves (must
+    include its own validity leaf). A stable argsort of ``dest`` ranks each
+    row within its bucket; rows ranked past C drop to a scratch slot.
+    Returns (pytree of (p·C, …), overflow, max_fill): overflow counts the
+    dropped rows, and max_fill is the largest bucket demand observed — the
+    capacity that *would* have fit, independent of C, so one retry sized
+    from it always succeeds."""
+    n = dest.shape[0]
+    order = _argsort(dest)
+    ds, payload = _permute(order, dest, payload)
+    counts = jnp.bincount(ds, length=p)
+    starts = jnp.cumsum(counts) - counts
+    pos = jnp.arange(n) - starts[ds]
+    keep = pos < C
+    slot = jnp.where(keep, ds * C + pos, p * C)  # overflow → scratch slot
+    overflow = (n - keep.sum()).astype(jnp.int32)
+    max_fill = counts.max().astype(jnp.int32)
 
-    ``route`` (optional) is a kernel-backed router ``dest -> (pos, keep,
-    counts)`` (kernels/moe_route.bucket_route, docs/kernels.md): capacity
-    ordinals in row order — exactly the rank the stable argsort below
-    assigns, so kept rows land in the same unique slots and the packed
-    buffer is bit-identical; only the sliced-off overflow scratch slot can
-    differ (duplicate writes, different order).
-    """
+    def pack(x):
+        buf = jnp.zeros((p * C + 1, *x.shape[1:]), x.dtype)
+        buf = buf.at[slot].set(x)
+        return buf[: p * C]
+
+    return jax.tree.map(pack, payload), overflow, max_fill
+
+
+def _pack_exchange(dest, payload, axis, p, C):
+    """Inside shard_map: route rows to ``dest`` buckets with capacity C
+    (``_pack``) and exchange them. Returns (pytree of (p·C, …), overflow,
+    max_fill). Dropped rows (bucket overflow) are counted, not silently
+    lost."""
     with _scope(EXCHANGE):
-        n = dest.shape[0]
-        if route is not None:
-            pos, keep, counts = route(dest)
-            slot = jnp.where(keep, dest * C + pos, p * C)
-        else:
-            order = _argsort(dest)
-            ds, payload = _permute(order, dest, payload)
-            counts = jnp.bincount(ds, length=p)
-            starts = jnp.cumsum(counts) - counts
-            pos = jnp.arange(n) - starts[ds]
-            keep = pos < C
-            slot = jnp.where(keep, ds * C + pos, p * C)  # overflow → scratch slot
-        overflow = (n - keep.sum()).astype(jnp.int32)
-        max_fill = counts.max().astype(jnp.int32)
-
-        def pack(x):
-            buf = jnp.zeros((p * C + 1, *x.shape[1:]), x.dtype)
-            buf = buf.at[slot].set(x)
-            return buf[: p * C]
-
-        return _exchange(jax.tree.map(pack, payload), axis, p, C), overflow, max_fill
+        packed, overflow, max_fill = _pack(dest, payload, p, C)
+        return _exchange(packed, axis, p, C), overflow, max_fill
 
 
 def _pack_sorted(dest, payload, end, axis, p, C):
@@ -404,11 +403,10 @@ def sort_stage(ctx: IContext, keys, valid, data, C: int, post=None):
     return fn(*uniq)
 
 
-def hash_stage(ctx: IContext, keys, valid, data, C: int, post=None, route=None):
+def hash_stage(ctx: IContext, keys, valid, data, C: int, post=None):
     """One fused wide hash-exchange stage (partitionBy / reduce routing), no
     host syncs. Same contract as ``sort_stage``; equal keys land on one
-    executor but arrive unsorted. ``route`` is the optional kernel-backed
-    bucket router (see ``_pack_exchange``)."""
+    executor but arrive unsorted."""
     post = post or _passthrough
     p = ctx.executors
     zero = jnp.zeros((), jnp.int32)
@@ -421,7 +419,7 @@ def hash_stage(ctx: IContext, keys, valid, data, C: int, post=None, route=None):
             dest = (_hash_u32(k) % jnp.uint32(p)).astype(jnp.int32)
             dest = jnp.where(v, dest, p - 1)  # park invalid rows anywhere stable
         payload = {"k": k, "valid": v, "data": d}
-        out, overflow, fill = _pack_exchange(dest, payload, ctx.axis, p, C, route)
+        out, overflow, fill = _pack_exchange(dest, payload, ctx.axis, p, C)
         with _scope(POST):
             out = post(out["k"], out["valid"], out["data"])
         return (
@@ -440,15 +438,13 @@ def hash_stage(ctx: IContext, keys, valid, data, C: int, post=None, route=None):
 
 
 def join_stage(ctx: IContext, lk, lvalid, lvals, rk, rvalid, rvals,
-               Cl: int, Cr: int, M: int, route_l=None, route_r=None):
+               Cl: int, Cr: int, M: int):
     """Both-side hash exchange + local sort-merge join in ONE wide stage.
 
     Returns ``(rows, ok, exch_overflow, lfill, rfill, fan_overflow)`` — four
     replicated int32 scalars fetched by the caller in a single deferred sync:
     exchange overflow retries with capacities sized from the fills; fan-out
-    overflow retries with a doubled per-key match bound M. ``route_l`` /
-    ``route_r`` are per-side kernel-backed bucket routers (capacity-specific:
-    Cl ≠ Cr — see ``_pack_exchange``).
+    overflow retries with a doubled per-key match bound M.
     """
     p = ctx.executors
     zero = jnp.zeros((), jnp.int32)
@@ -461,9 +457,9 @@ def join_stage(ctx: IContext, lk, lvalid, lvals, rk, rvalid, rvals,
             ldest = jnp.where(lv_, (_hash_u32(lk_) % jnp.uint32(p)).astype(jnp.int32), p - 1)
             rdest = jnp.where(rv_, (_hash_u32(rk_) % jnp.uint32(p)).astype(jnp.int32), p - 1)
         lout, lovf, lfill = _pack_exchange(
-            ldest, {"k": lk_, "valid": lv_, "data": ld_}, ctx.axis, p, Cl, route_l)
+            ldest, {"k": lk_, "valid": lv_, "data": ld_}, ctx.axis, p, Cl)
         rout, rovf, rfill = _pack_exchange(
-            rdest, {"k": rk_, "valid": rv_, "data": rd_}, ctx.axis, p, Cr, route_r)
+            rdest, {"k": rk_, "valid": rv_, "data": rd_}, ctx.axis, p, Cr)
         rows, ok, fovf = local_join(
             lout["k"], lout["valid"], lout["data"],
             rout["k"], rout["valid"], rout["data"], M)
@@ -483,35 +479,6 @@ def join_stage(ctx: IContext, lk, lvalid, lvals, rk, rvalid, rvals,
         out_specs=(P(ctx.axis), P(ctx.axis), P(), P(), P(), P()),
     )
     return fn(lk, lvalid, lvals, rk, rvalid, rvals)
-
-
-# ---------------------------------------------------------------------------
-# legacy single-shot wrappers (direct-primitive tests; no retry, no memory)
-# ---------------------------------------------------------------------------
-
-
-def psrs_sort(ctx: IContext, keys, valid, data, capacity_factor=2.0):
-    """Distributed sort by `keys`. All inputs axis-sharded on dim 0.
-
-    Returns (keys', valid', data', overflow) — globally sorted (shard i holds
-    keys ≤ shard i+1), invalid rows pushed to the tail of each shard."""
-    p = ctx.executors
-    C = capacity_for(capacity_factor, keys.shape[0] // max(p, 1), p)
-    out, ovf, _ = sort_stage(ctx, keys, valid, data, C, post=lambda k, v, d: (k, v, d))
-    k, v, d = out
-    return k, v, d, ovf
-
-
-def hash_exchange(ctx: IContext, keys, valid, data, capacity_factor=2.0):
-    """Route rows so equal keys land on the same executor. Same-shape padded
-    output + overflow count."""
-    p = ctx.executors
-    if p == 1:
-        return keys, valid, data, jnp.zeros((), jnp.int32)
-    C = capacity_for(capacity_factor, keys.shape[0] // p, p)
-    out, ovf, _ = hash_stage(ctx, keys, valid, data, C, post=lambda k, v, d: (k, v, d))
-    k, v, d = out
-    return k, v, d, ovf
 
 
 # ---------------------------------------------------------------------------
@@ -649,17 +616,6 @@ def make_reduce_post_kernel(op: str, identity, block: int, interpret: bool):
         return {"key": data["key"], "value": value}, tails
 
     return post
-
-
-def make_bucket_route(p: int, C: int, block: int, interpret: bool):
-    """Kernel-backed exchange router for ``_pack_exchange`` (module-level
-    so plan-cache keys stay stable across rebuilds)."""
-    from repro.kernels.moe_route.ops import bucket_route
-
-    def route(dest):
-        return bucket_route(dest, p, C, block=block, interpret=interpret)
-
-    return route
 
 
 def make_group_post(G: int):

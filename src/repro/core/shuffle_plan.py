@@ -397,35 +397,6 @@ class ShuffleManager:
 
         return self.kernels.tune(key, self._block_candidates(n), timer)
 
-    def _tune_route(self, n_local: int, sel) -> int:
-        """Tuned block size for the bucket router at this exchange width."""
-        p = self.p
-        n = max(int(n_local), 1)
-        key = ("bucket_route", p, n, sel.interpret, jax.default_backend())
-
-        def timer(c: int) -> float:
-            route = sh.make_bucket_route(p, max(n // p, 1), c, sel.interpret)
-            f = jax.jit(route)
-            return self._time_calls(f, jnp.zeros(n, jnp.int32))
-
-        return self.kernels.tune(key, self._block_candidates(n), timer)
-
-    def _select_route(self, sig, n_local: int):
-        """Kernel-or-fallback decision for a hash-routed exchange: returns
-        (selection, tuned_block), (None, None) for the argsort path."""
-        if self.p <= 1:  # no exchange, nothing to route
-            return None, None
-        sel = self.kernels.select("bucket_route")
-        if sel is None:
-            return None, None
-        try:
-            blk = self._tune_route(n_local, sel)
-        except Exception as e:
-            self.kernels.degrade(sel, e)
-            return None, None
-        self._note(sig, f"{sel.describe()} block={blk}")
-        return sel, blk
-
     # ------------------------------------------------------------------
     # sort-routed wide ops (sort / distinct / reduceByKey / groupByKey)
     # ------------------------------------------------------------------
@@ -491,7 +462,7 @@ class ShuffleManager:
         # fns, pytree values, unsupported dtypes) keeps the jnp oracle
         value = b.data.get("value") if isinstance(b.data, dict) else None
         op = self._reduce_op(fn, identity, value)
-        sel = self.kernels.select("segment_reduce") if op is not None else None
+        sel = self.kernels.select() if op is not None else None
         if sel is not None:
             try:
                 blk = self._tune_reduce(sig, b, op, sel)
@@ -526,34 +497,24 @@ class ShuffleManager:
         b = self._placed(b)
         rows = b.capacity
         n_local = rows // max(self.p, 1)
-        sel, blk = self._select_route(sig, n_local)
         data, valid = self._adaptive(
-            sig, rows, n_local,
-            lambda C: self._run_hash_stage(C, b, key_fn, sel=sel, blk=blk))
+            sig, rows, n_local, lambda C: self._run_hash_stage(C, b, key_fn))
         return Block(data, valid)
 
-    def _run_hash_stage(self, C, b, key_fn, sel=None, blk=None):
+    def _run_hash_stage(self, C, b, key_fn):
         ctx = self.ctx
-        route = None
-        ktag = ()
-        if sel is not None:
-            route = sh.make_bucket_route(self.p, C, blk, sel.interpret)
-            ktag = ("kernel", blk, sel.interpret)
-        key = (("partitionBy",) + ktag, C, fn_token(key_fn), _block_aval(b), ctx.mesh)
+        key = ("partitionBy", C, fn_token(key_fn), _block_aval(b), ctx.mesh)
 
         def builder():
             def run(data, valid):
                 keys = jax.vmap(key_fn)(data)
-                return sh.hash_stage(ctx, keys, valid, data, C, route=route)
+                return sh.hash_stage(ctx, keys, valid, data, C)
 
             return run, None
 
         fn, _ = self._plan(key, builder)
         self._account(b, C)
         faults.check("shuffle.stage", kind="partitionBy", p=self.p)
-        if sel is not None:
-            faults.check("kernel.stage", kind="partitionBy",
-                         kernel="bucket_route", p=self.p)
         return fn(b.data, b.valid)
 
     # ------------------------------------------------------------------
@@ -569,27 +530,18 @@ class ShuffleManager:
         factor = self._factor(sig, (nl, nr))
         with self._plan_lock:
             M = self._fanout.get((sig, nl, nr, p), max_matches)
-        sel, blk = self._select_route(sig, max(nl_local, nr_local))
         ctx = self.ctx
         attempts = 0
         while True:
             attempts += 1
             Cl = sh.capacity_for(factor, nl_local, p)
             Cr = sh.capacity_for(factor, nr_local, p)
-            route_l = route_r = None
-            ktag = ()
-            if sel is not None:
-                route_l = sh.make_bucket_route(p, Cl, blk, sel.interpret)
-                route_r = sh.make_bucket_route(p, Cr, blk, sel.interpret)
-                ktag = ("kernel", blk, sel.interpret)
-            key = (("join", M) + ktag, Cl, Cr, _block_aval(lb), _block_aval(rb),
-                   ctx.mesh)
+            key = ("join", M, Cl, Cr, _block_aval(lb), _block_aval(rb), ctx.mesh)
 
-            def builder(Cl=Cl, Cr=Cr, M=M, route_l=route_l, route_r=route_r):
+            def builder(Cl=Cl, Cr=Cr, M=M):
                 def run(ld, lv, rd, rv):
                     return sh.join_stage(ctx, ld["key"], lv, ld["value"],
-                                         rd["key"], rv, rd["value"], Cl, Cr, M,
-                                         route_l=route_l, route_r=route_r)
+                                         rd["key"], rv, rd["value"], Cl, Cr, M)
 
                 return run, None
 
@@ -598,9 +550,6 @@ class ShuffleManager:
                 self._account(lb, Cl)
                 self._account(rb, Cr)
             faults.check("shuffle.stage", kind="join", p=p, attempt=attempts - 1)
-            if sel is not None:
-                faults.check("kernel.stage", kind="join", kernel="bucket_route",
-                             p=p, attempt=attempts - 1)
             rows, ok, eovf, lfill, rfill, fovf = fn(lb.data, lb.valid, rb.data, rb.valid)
             # one deferred check covers both exchanges AND the fan-out bound
             self._bump("overflow_checks")

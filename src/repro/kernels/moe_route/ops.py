@@ -1,4 +1,4 @@
-"""Public routing wrappers: CPU auto-interpret (and padding for moe_route)."""
+"""Public MoE routing wrapper: CPU auto-interpret and padding."""
 from __future__ import annotations
 
 import jax
@@ -9,23 +9,6 @@ from repro.kernels.moe_route.moe_route import moe_route_fwd
 
 def _should_interpret():
     return jax.default_backend() != "tpu"
-
-
-def bucket_route(dest, p: int, capacity: int, block: int = 8192, interpret=None):
-    """Shuffle-exchange routing (route.py): capacity ordinals in row order.
-
-    dest: (N,) int32 in [0, p). Returns (pos (N,) i32, keep (N,) bool,
-    counts (p,) i32) — bit-identical to the stable-argsort formulation in
-    core/shuffle._pack_exchange (and to ``bucket_route_ref``)."""
-    from repro.kernels.moe_route.route import bucket_route_fwd
-
-    interpret = _should_interpret() if interpret is None else interpret
-    (N,) = dest.shape
-    if N == 0:
-        return (jnp.zeros(0, jnp.int32), jnp.zeros(0, bool),
-                jnp.zeros(p, jnp.int32))
-    return bucket_route_fwd(dest, p=p, capacity=capacity, block=block,
-                            interpret=interpret)
 
 
 def moe_route(logits, k: int, capacity: int, block_t: int = 256, interpret=None):

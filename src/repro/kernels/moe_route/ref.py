@@ -9,20 +9,6 @@ import jax
 import jax.numpy as jnp
 
 
-def bucket_route_ref(dest, p: int, capacity: int):
-    """Oracle for route.bucket_route: the stable-argsort formulation of
-    capacity ordinals (the exact code path of core/shuffle._pack_exchange,
-    inverted back to row order)."""
-    n = dest.shape[0]
-    order = jnp.argsort(dest, stable=True)
-    ds = dest[order]
-    counts = jnp.bincount(ds, length=p)
-    starts = jnp.cumsum(counts) - counts
-    pos_sorted = jnp.arange(n) - starts[ds]
-    pos = jnp.zeros(n, jnp.int32).at[order].set(pos_sorted.astype(jnp.int32))
-    return pos, pos < capacity, counts.astype(jnp.int32)
-
-
 def moe_route_ref(logits, k: int, capacity: int):
     """logits: (T, E). Returns (weights (T,k) f32, idx (T,k) i32,
     pos (T,k) i32 ordinal-within-expert, keep (T,k) bool)."""

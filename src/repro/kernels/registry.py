@@ -1,31 +1,31 @@
-"""Kernel tier: capability checks, mode resolution, autotune memo
+"""Kernel tier: mode resolution, capability probe, autotune memo
 (docs/kernels.md, DESIGN.md §11).
 
-The shuffle engine's wide stages (core/shuffle.py) each have a Pallas
-kernel implementation (segment_reduce / ssd_scan's prefix pass /
-moe_route's bucket router) and a plain-JAX oracle that is always
-available. This module decides, per wide node, which one runs:
+The shuffle engine's reduceByKey stage (core/shuffle_plan.py) has one
+Pallas kernel, ``segment_reduce``, beside a plain-JAX oracle that is
+always available. This module decides, per reduceByKey node, which one
+runs:
 
-* **Mode** (``ignis.kernels``): ``auto`` uses compiled Pallas where the
-  backend supports it and the plain-JAX fallback everywhere else (an
+* **Mode** (``ignis.kernels``): ``auto`` uses the compiled kernel where
+  the backend supports it and the plain-JAX fallback everywhere else (an
   interpreted kernel is strictly slower than the jnp oracle, so auto
   never interprets); ``on`` forces the kernel (compiled where available,
   ``interpret=True`` otherwise); ``interpret`` forces interpret mode
   (the CI conformance path); ``off`` forces the fallback.
-* **Capability probe**: a tiny invocation per (kernel, interpret,
+* **Capability probe**: a tiny invocation of the kernel per (interpret,
   backend), cached. An interpreted kernel that fails its probe degrades
   to the fallback; a compiled one raises ``KernelUnavailable`` with the
   compiler's message, and so does a failed autotune sweep of a compiled
   kernel (``degrade``): a chip run never quietly leaves the kernel path
   it claims to run. The ``kernel.capability`` fault site fires on every
   selection so chaos tests can force mid-job degradation.
-* **Autotune memo**: best block size per (kernel, aval, op) key, found
-  by a timed sweep over ``ignis.kernels.blocks`` candidates. The memo
-  is an LRU with single-builder discipline (per-key in-flight Event,
-  same pattern as comm.py's collective plan cache): concurrent misses
-  on one key cost exactly one sweep. Tuned blocks feed the wide-plan
-  cache key, so a repeat lineage pays zero re-tunes and zero
-  recompiles.
+* **Autotune memo**: best block size per (aval, op) key, found by the
+  shuffle engine's timed sweep (``ShuffleManager._tune_reduce``) over
+  ``ignis.kernels.blocks`` candidates. The memo is an LRU with
+  single-builder discipline (per-key in-flight Event, same pattern as
+  comm.py's collective plan cache): concurrent misses on one key cost
+  exactly one sweep. Tuned blocks feed the wide-plan cache key, so a
+  repeat lineage pays zero re-tunes and zero recompiles.
 
 Selection results and tune counts surface as ``kernel_hits`` /
 ``kernel_fallbacks`` / ``autotune_runs`` / ``autotune_evictions`` in
@@ -44,7 +44,7 @@ import numpy as np
 
 from repro.core.metrics import Counters
 from repro.core.properties import REGISTRY as PROPS
-from repro.kernels.ssd_scan.prefix import TILE
+from repro.kernels.segment_reduce.lanes import TILE
 
 #: dtypes the kernel tier computes natively (bool rides as i32)
 SUPPORTED_DTYPES = ("float32", "int32")
@@ -62,19 +62,22 @@ class KernelUnavailable(RuntimeError):
     """A compiled kernel failed its probe or its autotune sweep."""
 
 
+#: the one kernel the tier selects
+KERNEL = "segment_reduce"
+
+
 @dataclass(frozen=True)
 class Selection:
-    """A resolved kernel choice: which kernel, interpreted or compiled."""
+    """A resolved kernel choice: interpreted or compiled."""
 
-    kernel: str
     interpret: bool
 
     def describe(self) -> str:
-        return f"{self.kernel}[{'interpret' if self.interpret else 'compiled'}]"
+        return f"{KERNEL}[{'interpret' if self.interpret else 'compiled'}]"
 
 
 # ---------------------------------------------------------------------------
-# capability probes: one tiny invocation per kernel
+# capability probe: one tiny invocation of the kernel
 # ---------------------------------------------------------------------------
 
 
@@ -85,28 +88,6 @@ def _probe_segment_reduce(interpret: bool):
     hb = jnp.ones((TILE,), bool)
     jax.block_until_ready(
         segment_reduce_fwd(v, hb, op="sum", block=TILE, interpret=interpret))
-
-
-def _probe_prefix_scan(interpret: bool):
-    from repro.kernels.ssd_scan.prefix import prefix_scan_fwd
-
-    x = jnp.zeros((TILE,), jnp.int32)
-    jax.block_until_ready(prefix_scan_fwd(x, op="min", block=TILE, interpret=interpret))
-
-
-def _probe_bucket_route(interpret: bool):
-    from repro.kernels.moe_route.route import bucket_route_fwd
-
-    d = jnp.zeros((TILE,), jnp.int32)
-    jax.block_until_ready(
-        bucket_route_fwd(d, p=2, capacity=4, block=TILE, interpret=interpret))
-
-
-_PROBES: dict = {
-    "segment_reduce": _probe_segment_reduce,
-    "prefix_scan": _probe_prefix_scan,
-    "bucket_route": _probe_bucket_route,
-}
 
 
 # ---------------------------------------------------------------------------
@@ -190,26 +171,26 @@ class KernelRegistry:
     # ------------------------------------------------------------------
     # selection
     # ------------------------------------------------------------------
-    def _probe(self, kernel: str, interpret: bool) -> bool:
-        key = (kernel, interpret, jax.default_backend())
+    def _probe(self, interpret: bool) -> bool:
+        key = (interpret, jax.default_backend())
         with self._lock:
             if key in self._probe_cache:
                 return self._probe_cache[key]
         try:
-            _PROBES[kernel](interpret)
+            _probe_segment_reduce(interpret)
             ok = True
         except Exception as e:
             if not interpret:  # nothing cached: the next selection re-probes
                 raise KernelUnavailable(
-                    f"{kernel}: compiled probe failed on "
+                    f"{KERNEL}: compiled probe failed on "
                     f"{jax.default_backend()}: {e}") from e
             ok = False
         with self._lock:
             self._probe_cache[key] = ok
         return ok
 
-    def select(self, kernel: str) -> Optional[Selection]:
-        """Resolve one kernel-eligible wide node. None → plain-JAX
+    def select(self) -> Optional[Selection]:
+        """Resolve one kernel-eligible reduceByKey node. None → plain-JAX
         fallback (always available, bit-identical for exact ops).
 
         A ``kernel.capability`` fault or a failed interpreted probe
@@ -225,7 +206,7 @@ class KernelRegistry:
         if self.mode == "off":
             return self._fallback()
         try:
-            faults.check("kernel.capability", kernel=kernel)
+            faults.check("kernel.capability", kernel=KERNEL)
         except faults.FaultInjected:
             return self._fallback()
         if self.mode == "auto":
@@ -238,10 +219,10 @@ class KernelRegistry:
             interpret = True
         else:  # "on": compiled where the backend supports it
             interpret = not compiled_backend()
-        if not self._probe(kernel, interpret):
+        if not self._probe(interpret):
             return self._fallback()
         self._bump("kernel_hits")
-        return Selection(kernel, interpret)
+        return Selection(interpret)
 
     def _fallback(self) -> None:
         self._bump("kernel_fallbacks")
@@ -261,7 +242,7 @@ class KernelRegistry:
         Compiled: raise ``KernelUnavailable`` with the failure's message."""
         if not sel.interpret:
             raise KernelUnavailable(
-                f"{sel.kernel}: compiled autotune sweep failed on "
+                f"{KERNEL}: compiled autotune sweep failed on "
                 f"{jax.default_backend()}: {exc}") from exc
         self.demote()
 

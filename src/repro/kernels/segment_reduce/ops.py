@@ -11,9 +11,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.shuffle import segment_tails
+from repro.kernels.segment_reduce.lanes import op_identity
 from repro.kernels.segment_reduce.ref import heads_of
 from repro.kernels.segment_reduce.segment_reduce import segment_reduce_fwd
-from repro.kernels.ssd_scan.prefix import op_identity
 
 
 def _should_interpret():
@@ -51,6 +51,8 @@ def segment_reduce(keys, valid, values, op: str = "sum", block: int = 8192,
     """
     interpret = _should_interpret() if interpret is None else interpret
     ct = _compute_dtype(values.dtype)
+    if keys.shape[0] == 0:
+        return jnp.zeros(0, bool), values.astype(ct)
     heads, out, squeeze = _scan(keys, valid, values, op,
                                 op_identity(op, ct), block, interpret)
     return heads, (out[:, 0] if squeeze else out)

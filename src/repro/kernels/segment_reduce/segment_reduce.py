@@ -3,8 +3,8 @@
 Grid (D, n_blocks): one value column at a time, sequential over row tiles
 of that column; a ``(1, 128)`` VMEM tile carries the running segment value
 across tiles. Each column and the boundary flags are laid out lane-dense
-as ``(N/128, 128)`` (ssd_scan/prefix.py ``lane_layout``), and the in-tile
-segmented inclusive scan is prefix.py's ``scan_tile``: log-depth
+as ``(N/128, 128)`` (lanes.py ``lane_layout``), and the in-tile
+segmented inclusive scan is lanes.py's ``scan_tile``: log-depth
 ``pltpu.roll`` sweeps with iota masks, no HBM intermediates. Backs
 reduceByKey/groupBy of the dataflow layer (paper's TeraSort/K-Means path).
 
@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ssd_scan.prefix import LANES, lane_layout, scan_tile, tile_last, to_lanes
+from repro.kernels.segment_reduce.lanes import LANES, lane_layout, scan_tile, tile_last, to_lanes
 
 _FNS = {"sum": jnp.add, "max": jnp.maximum, "min": jnp.minimum}
 

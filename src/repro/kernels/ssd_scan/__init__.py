@@ -1,2 +1,2 @@
-from repro.kernels.ssd_scan.ops import prefix_scan, ssd_scan  # noqa: F401
-from repro.kernels.ssd_scan.ref import prefix_scan_ref, ssd_ref  # noqa: F401
+from repro.kernels.ssd_scan.ops import ssd_scan  # noqa: F401
+from repro.kernels.ssd_scan.ref import ssd_ref  # noqa: F401

@@ -1,9 +1,5 @@
-"""Oracles for the SSD scan kernel (= the model-side chunked SSD) and
-the prefix-scan kernel (= the lax cumulative primitives)."""
+"""Oracle for the SSD scan kernel (= the model-side chunked SSD)."""
 from __future__ import annotations
-
-import jax
-import jax.numpy as jnp
 
 from repro.models.mamba2 import ssd_chunked
 
@@ -12,18 +8,3 @@ def ssd_ref(x, dt, A_log, Bm, Cm, chunk):
     """x: (b, s, h, p); dt: (b, s, h) (softplus applied); A_log: (h,);
     Bm/Cm: (b, s, g, n). Returns (y, final_state)."""
     return ssd_chunked(x, dt, A_log, Bm, Cm, chunk)
-
-
-_CUM = {"sum": jnp.cumsum, "max": jax.lax.cummax, "min": jax.lax.cummin}
-
-
-def prefix_scan_ref(x, op: str = "sum", reverse: bool = False):
-    """Inclusive scan via the lax cumulative primitives; ``reverse=True``
-    scans from the tail (suffix scan)."""
-    v = x.astype(jnp.int32) if x.dtype == jnp.bool_ else x
-    if reverse:
-        v = v[::-1]
-    out = _CUM[op](v)
-    if reverse:
-        out = out[::-1]
-    return out.astype(bool) if x.dtype == jnp.bool_ else out

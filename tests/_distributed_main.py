@@ -449,9 +449,9 @@ def main():
     check("moe_ep_parity", float(jnp.abs(y_ep - y_ref).max()) < 1e-4)
 
     # ---- kernel tier at p=8 (docs/kernels.md): interpret vs off must be
-    # bit-identical with identical retry trajectories, with the kernels
-    # actually engaged on the exchange paths (segment_reduce post on
-    # reduceByKey, bucket_route on partitionBy/join)
+    # bit-identical with identical retry trajectories, with the segment
+    # kernel actually engaged on reduceByKey (partitionBy and join run the
+    # same exchange on both tiers)
     res8, ctr8 = {}, {}
     for mode in ("interpret", "off"):
         wk = IWorker(ICluster(IProperties({
@@ -476,7 +476,7 @@ def main():
         sk = wk.shuffle_stats()
         ctr8[mode] = (sk["overflow_retries"], sk["fanout_retries"])
         if mode == "interpret":
-            check("p8_kernel_hits", sk["kernel_hits"] >= 3)
+            check("p8_kernel_hits", sk["kernel_hits"] >= 1)
         else:
             check("p8_kernel_off_no_hits", sk["kernel_hits"] == 0)
     check("p8_kernel_on_off_equal", res8["interpret"] == res8["off"])

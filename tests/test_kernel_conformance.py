@@ -2,13 +2,14 @@
 
 Three layers, each against an always-available oracle:
 
-  * **kernel conformance** — every shuffle-tier kernel (prefix_scan,
-    segment_totals, bucket_route) × op (sum/max/min) × dtype
+  * **kernel conformance** — the shuffle-tier kernel (segment_reduce and
+    its stage entry segment_totals) × op (sum/max/min) × dtype
     (f32/i32/bool) × edge shape (ragged / empty / single-segment /
-    all-invalid) is BIT-identical to its ref.py / core/shuffle oracle in
-    interpret mode. f32 sums use integer-valued data (< 2^24) so the
-    association order cannot show: bit-identity is the contract, not a
-    tolerance (ISSUE 7).
+    all-invalid) is BIT-identical to the lax cumulative primitives and the
+    core/shuffle oracle in interpret mode, and the hash exchange's pack
+    step matches a NumPy stable-argsort reference. f32 sums use
+    integer-valued data (< 2^24) so the association order cannot show:
+    bit-identity is the contract, not a tolerance.
   * **registry semantics** — mode resolution, capability-probe failure
     degrading to the fallback, builtin-op recognition, and the autotune
     memo's LRU + single-builder discipline (comm.py plan-cache pattern).
@@ -31,10 +32,8 @@ from repro.core import shuffle as sh
 from repro.core.faults import FaultPlan
 from repro.core.shuffle import segmented_reduce
 from repro.kernels import registry as reg
-from repro.kernels.moe_route import bucket_route, bucket_route_ref
 from repro.kernels.registry import KernelRegistry, builtin_reduce_op
-from repro.kernels.segment_reduce import segment_totals
-from repro.kernels.ssd_scan import prefix_scan, prefix_scan_ref
+from repro.kernels.segment_reduce import segment_reduce, segment_totals
 
 KEY = jax.random.PRNGKey(11)
 
@@ -58,25 +57,23 @@ def _data(n, dtype, seed=0):
 
 
 # ---------------------------------------------------------------------------
-# prefix_scan — op × dtype × size (ragged/empty/single) × direction
+# segment_reduce over one segment — a prefix scan: op × dtype × size
+# (ragged/empty/single)
 # ---------------------------------------------------------------------------
+
+_CUM = {"sum": jnp.cumsum, "max": jax.lax.cummax, "min": jax.lax.cummin}
 
 
 @pytest.mark.parametrize("op", OPS)
 @pytest.mark.parametrize("dtype", ["float32", "int32", "bool"])
 @pytest.mark.parametrize("n", [0, 1, 5, 64, 200, 513])
-def test_prefix_scan_matches_ref(op, dtype, n):
+def test_single_segment_scan_matches_cumulative(op, dtype, n):
+    # every key equal, every row valid: the segmented scan is the inclusive
+    # prefix scan, in the compute dtype (bool rides as i32)
     x = _data(n, dtype, seed=n)
-    for reverse in (False, True):
-        got = prefix_scan(x, op=op, block=64, interpret=True, reverse=reverse)
-        assert bits_equal(got, prefix_scan_ref(x, op=op, reverse=reverse))
-
-
-def test_prefix_scan_block_size_is_invisible():
-    x = _data(300, "int32")
-    ref = prefix_scan_ref(x)
-    for block in (1, 7, 128, 512):
-        assert bits_equal(prefix_scan(x, block=block, interpret=True), ref)
+    _, got = segment_reduce(jnp.zeros(n, jnp.int32), jnp.ones(n, bool), x,
+                            op=op, block=64, interpret=True)
+    assert bits_equal(got, _CUM[op](x.astype(jnp.int32) if dtype == "bool" else x))
 
 
 # ---------------------------------------------------------------------------
@@ -143,6 +140,17 @@ def test_segment_totals_nonzero_identity_at_invalid_rows():
     assert bool((t1[~valid] == 41).all())
 
 
+def test_segment_totals_block_size_is_invisible():
+    # 3000 rows: the smaller blocks take several grid steps, so the carry
+    # across row tiles is exercised, and every block gives the same bits
+    keys, valid, vals = _segments(3000, 40, 0.9, "int32", seed=4)
+    ref = segmented_reduce(keys, valid, vals, _FNS["sum"], jnp.int32(0))
+    for block in (1, 7, 1024, 2048, 4096):
+        got = segment_totals(keys, valid, vals, "sum", jnp.int32(0),
+                             block=block, interpret=True)
+        assert all(bits_equal(g, r) for g, r in zip(got, ref))
+
+
 @pytest.mark.parametrize("n", [16, 17, 100])
 def test_chunked_scan_matches_associative_scan(n):
     # the oracle scans long inputs chunk by chunk (XLA:TPU compiles one
@@ -168,32 +176,54 @@ def test_segment_totals_matches_oracle_across_scan_chunks():
 
 
 # ---------------------------------------------------------------------------
-# bucket_route — exchange ordinals vs the stable-argsort oracle
+# hash exchange pack step — bucket slots vs a NumPy stable-argsort reference
 # ---------------------------------------------------------------------------
+
+
+def _pack_ref(dest, p, capacity):
+    """Each kept row's slot in the (p·C) buffer, overflow and max_fill: a
+    stable sort by destination ranks each row within its bucket."""
+    n = dest.shape[0]
+    order = np.argsort(dest, kind="stable")
+    counts = np.bincount(dest, minlength=p)
+    pos = np.empty(n, np.int64)
+    pos[order] = np.arange(n) - (np.cumsum(counts) - counts)[dest[order]]
+    keep = pos < capacity
+    slots = np.full(p * capacity, -1)
+    slots[(dest * capacity + pos)[keep]] = np.nonzero(keep)[0]
+    return slots, n - int(keep.sum()), int(counts.max())
+
+
+def _check_pack(dest, p, capacity):
+    n = dest.shape[0]
+    rows = {"row": jnp.arange(n, dtype=jnp.int32), "valid": jnp.ones(n, bool)}
+    packed, overflow, max_fill = sh._pack(jnp.asarray(dest), rows, p, capacity)
+    slots, ovf, fill = _pack_ref(dest, p, capacity)
+    got = np.where(np.asarray(packed["valid"]), np.asarray(packed["row"]), -1)
+    assert np.array_equal(got, slots)
+    assert int(overflow) == ovf and int(max_fill) == fill
 
 
 @pytest.mark.parametrize("n,p,capacity", [
     (0, 4, 2),          # empty
     (1, 2, 1),          # single row
     (100, 8, 20),       # roomy
-    (100, 8, 5),        # tight: overflow rows dropped by keep
-    (600, 2, 400),      # multi-block
+    (100, 8, 5),        # tight: overflow rows dropped
+    (600, 2, 400),      # large buckets
     (257, 5, 1),        # capacity 1, ragged tail
 ])
-def test_bucket_route_matches_ref(n, p, capacity):
-    dest = jnp.asarray(
-        np.random.default_rng(n + p).integers(0, p, n).astype(np.int32))
-    got = bucket_route(dest, p, capacity, block=64, interpret=True)
-    ref = bucket_route_ref(dest, p, capacity)
-    for g, r in zip(got, ref):
-        assert bits_equal(g, r)
+def test_pack_matches_stable_argsort(n, p, capacity):
+    rng = np.random.default_rng(n + p)
+    _check_pack(rng.integers(0, p, n).astype(np.int32), p, capacity)
 
 
-def test_bucket_route_all_one_destination():
-    dest = jnp.zeros(90, jnp.int32)
-    pos, keep, counts = bucket_route(dest, 4, 100, block=32, interpret=True)
-    assert bits_equal(pos, jnp.arange(90, dtype=jnp.int32))
-    assert bool(keep.all()) and counts[0] == 90 and int(counts.sum()) == 90
+def test_pack_all_one_destination():
+    dest = np.zeros(90, np.int32)
+    _check_pack(dest, 4, 100)
+    packed, overflow, max_fill = sh._pack(
+        jnp.asarray(dest), {"row": jnp.arange(90, dtype=jnp.int32)}, 4, 100)
+    assert bits_equal(packed["row"][:90], jnp.arange(90, dtype=jnp.int32))
+    assert int(overflow) == 0 and int(max_fill) == 90
 
 
 # ---------------------------------------------------------------------------
@@ -208,14 +238,14 @@ def test_registry_rejects_unknown_mode():
 
 def test_mode_off_always_falls_back():
     r = KernelRegistry(mode="off")
-    assert r.select("segment_reduce") is None
+    assert r.select() is None
     assert r.stats == {"kernel_hits": 0, "kernel_fallbacks": 1,
                        "autotune_runs": 0, "autotune_evictions": 0}
 
 
 def test_mode_auto_never_interprets_off_tpu():
     r = KernelRegistry(mode="auto")
-    sel = r.select("segment_reduce")
+    sel = r.select()
     if reg.compiled_backend():
         assert sel is not None and not sel.interpret
     else:  # interpreted Pallas is strictly slower than the jnp oracle
@@ -224,15 +254,15 @@ def test_mode_auto_never_interprets_off_tpu():
 
 def test_mode_interpret_selects_interpreted_kernel():
     r = KernelRegistry(mode="interpret")
-    sel = r.select("bucket_route")
+    sel = r.select()
     assert sel is not None and sel.interpret
-    assert sel.describe() == "bucket_route[interpret]"
+    assert sel.describe() == "segment_reduce[interpret]"
     assert r.stats["kernel_hits"] == 1
 
 
 def test_mode_on_uses_interpret_where_not_compiled():
     r = KernelRegistry(mode="on")
-    sel = r.select("prefix_scan")
+    sel = r.select()
     assert sel is not None
     assert sel.interpret == (not reg.compiled_backend())
 
@@ -241,28 +271,27 @@ def test_probe_failure_degrades_to_fallback(monkeypatch):
     def boom(interpret):
         raise RuntimeError("no such kernel on this backend")
 
-    monkeypatch.setitem(reg._PROBES, "segment_reduce", boom)
+    monkeypatch.setattr(reg, "_probe_segment_reduce", boom)
     r = KernelRegistry(mode="interpret")
-    assert r.select("segment_reduce") is None
+    assert r.select() is None
     assert r.stats["kernel_fallbacks"] == 1
     # the probe result is cached: a second select does not re-probe
-    monkeypatch.setitem(reg._PROBES, "segment_reduce",
-                        lambda interpret: None)
-    assert r.select("segment_reduce") is None
+    monkeypatch.setattr(reg, "_probe_segment_reduce", lambda interpret: None)
+    assert r.select() is None
 
 
 def test_capability_fault_degrades_without_error():
     r = KernelRegistry(mode="interpret")
     plan = FaultPlan().fail_kernel_capability("segment_reduce", times=1)
     with faults.inject(plan):
-        assert r.select("segment_reduce") is None      # degraded
-        assert r.select("segment_reduce") is not None  # times=1: recovered
+        assert r.select() is None      # degraded
+        assert r.select() is not None  # times=1: recovered
     assert r.stats["kernel_fallbacks"] == 1 and r.stats["kernel_hits"] == 1
 
 
 def test_demote_rebooks_hit_as_fallback():
     r = KernelRegistry(mode="interpret")
-    assert r.select("prefix_scan") is not None
+    assert r.select() is not None
     r.demote()
     assert r.stats == {"kernel_hits": 0, "kernel_fallbacks": 1,
                        "autotune_runs": 0, "autotune_evictions": 0}
@@ -391,8 +420,8 @@ def _worker(mode, **props):
 
 _VALS = np.random.default_rng(2).integers(0, 10_000, 512).astype(np.int32)
 
-# kind → (pipeline, kernel-eligible at p=1?) — partitionBy/join consult the
-# router only when there is an exchange (p > 1): see _distributed_main.py
+# kind → (pipeline, kernel-eligible?) — only reduceByKey runs the segment
+# kernel; the p=8 twin is in _distributed_main.py
 _KINDS = {
     "sort": (lambda df: df.sort(), False),
     "distinct": (lambda df: df.map(lambda x: x % 17).distinct(), False),
@@ -562,24 +591,23 @@ def test_compiled_probe_failure_raises(monkeypatch, mode):
         raise RuntimeError("Mosaic failed to compile TPU kernel: boom")
 
     monkeypatch.setattr(reg, "compiled_backend", lambda: True)
-    monkeypatch.setitem(reg._PROBES, "segment_reduce", boom)
+    monkeypatch.setattr(reg, "_probe_segment_reduce", boom)
     r = KernelRegistry(mode=mode)
     with pytest.raises(reg.KernelUnavailable,
                        match="segment_reduce.*Mosaic failed to compile"):
-        r.select("segment_reduce")
+        r.select()
     assert r.stats["kernel_fallbacks"] == 0
     # nothing was cached: a repaired backend selects the kernel next time
-    monkeypatch.setitem(reg._PROBES, "segment_reduce", lambda interpret: None)
-    sel = r.select("segment_reduce")
+    monkeypatch.setattr(reg, "_probe_segment_reduce", lambda interpret: None)
+    sel = r.select()
     assert sel is not None and not sel.interpret
 
 
 def test_compiled_tune_failure_raises_from_the_wide_stage(monkeypatch):
-    # the probes pass, but the sweep's compiled kernel cannot run on this
+    # the probe passes, but the sweep's compiled kernel cannot run on this
     # host: the action must raise, naming the kernel, not fall back
     monkeypatch.setattr(reg, "compiled_backend", lambda: True)
-    for name in list(reg._PROBES):
-        monkeypatch.setitem(reg._PROBES, name, lambda interpret: None)
+    monkeypatch.setattr(reg, "_probe_segment_reduce", lambda interpret: None)
     w = _worker("auto", **{"ignis.kernels.blocks": "1024,2048"})
     vals = np.arange(4096, dtype=np.int32)
     df = (w.parallelize(vals).map(lambda x: {"key": x % 13, "value": x})
@@ -591,6 +619,6 @@ def test_compiled_tune_failure_raises_from_the_wide_stage(monkeypatch):
 
 def test_interpreted_tune_failure_still_degrades():
     r = KernelRegistry(mode="interpret")
-    sel = r.select("bucket_route")
+    sel = r.select()
     r.degrade(sel, RuntimeError("boom"))
     assert r.stats["kernel_hits"] == 0 and r.stats["kernel_fallbacks"] == 1

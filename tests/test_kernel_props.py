@@ -11,15 +11,16 @@ pytest.importorskip("hypothesis", reason="dev-only dependency (requirements-dev.
 from hypothesis import HealthCheck, given, settings, strategies as st
 
 from repro.core import ICluster, IProperties, IWorker
+from repro.core import shuffle as sh
 from repro.core.shuffle import segmented_reduce
-from repro.kernels.moe_route import bucket_route, bucket_route_ref
-from repro.kernels.segment_reduce import segment_totals
-from repro.kernels.ssd_scan import prefix_scan, prefix_scan_ref
+from repro.kernels.segment_reduce import segment_reduce, segment_totals
 
 _settings = settings(max_examples=12, deadline=None,
                      suppress_health_check=list(HealthCheck))
 
 _FNS = {"sum": lambda a, b: a + b, "max": jnp.maximum, "min": jnp.minimum}
+_CUM = {"sum": np.cumsum, "max": np.maximum.accumulate,
+        "min": np.minimum.accumulate}
 ops = st.sampled_from(["sum", "max", "min"])
 ints = st.lists(st.integers(-1000, 1000), min_size=1, max_size=300)
 
@@ -40,12 +41,15 @@ def worker():
     return _worker
 
 
-@given(ints, ops, st.sampled_from([7, 64, 256]), st.booleans())
+@given(ints, ops, st.sampled_from([7, 64, 256, 2048]))
 @_settings
-def test_prefix_scan_random(xs, op, block, reverse):
-    x = jnp.asarray(xs, jnp.int32)
-    got = prefix_scan(x, op=op, block=block, interpret=True, reverse=reverse)
-    assert bits_equal(got, prefix_scan_ref(x, op=op, reverse=reverse))
+def test_single_segment_scan_random(xs, op, block):
+    # one segment (every key equal, every row valid) is a prefix scan
+    x = np.asarray(xs, np.int32)
+    n = x.shape[0]
+    _, got = segment_reduce(jnp.zeros(n, jnp.int32), jnp.ones(n, bool),
+                            jnp.asarray(x), op=op, block=block, interpret=True)
+    assert bits_equal(got, _CUM[op](x).astype(np.int32))
 
 
 @given(st.lists(st.tuples(st.integers(0, 9), st.integers(-100, 100),
@@ -69,11 +73,23 @@ def test_segment_totals_random(rows, op, dtype):
 @given(st.integers(1, 8), st.integers(1, 64),
        st.lists(st.integers(0, 7), min_size=1, max_size=300))
 @_settings
-def test_bucket_route_random(p, capacity, dest):
-    d = jnp.asarray([x % p for x in dest], jnp.int32)
-    got = bucket_route(d, p, capacity, block=64, interpret=True)
-    ref = bucket_route_ref(d, p, capacity)
-    assert all(bits_equal(g, r) for g, r in zip(got, ref))
+def test_pack_random(p, capacity, dest):
+    # the hash exchange's pack step vs a stable sort by destination: each
+    # kept row in its bucket's slot, in row order, and the overflow and
+    # largest bucket demand counted
+    d = np.asarray([x % p for x in dest], np.int32)
+    n = d.shape[0]
+    rows = {"row": jnp.arange(n, dtype=jnp.int32), "valid": jnp.ones(n, bool)}
+    packed, overflow, max_fill = sh._pack(jnp.asarray(d), rows, p, capacity)
+    counts = np.bincount(d, minlength=p)
+    want = np.full(p * capacity, -1)
+    for b in range(p):
+        mine = np.nonzero(d == b)[0][:capacity]
+        want[b * capacity: b * capacity + mine.size] = mine
+    got = np.where(np.asarray(packed["valid"]), np.asarray(packed["row"]), -1)
+    assert np.array_equal(got, want)
+    assert int(overflow) == n - int(np.minimum(counts, capacity).sum())
+    assert int(max_fill) == int(counts.max())
 
 
 @given(st.lists(st.integers(0, 2**15 - 1), min_size=1, max_size=60),

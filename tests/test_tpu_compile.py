@@ -2,12 +2,12 @@
 
 The TPU compiler is installed beside the CPU backend, and it compiles for a
 chip that is described rather than attached. These tests lower the main
-path's Pallas kernels at N = 2^20 for a described ``v5e:2x2`` and assert that
-Mosaic accepted them (``tpu_custom_call`` in the compiled HLO): interpret
-mode cannot show a tiling or lowering error, this can. One wide stage is
-compiled over the four described devices, so the exchange's ``all-to-all``
-and the routing kernel are checked together, and the one-chip sort stage is
-compiled to check that its payload rides in the sort (about 30 s here).
+path's Pallas kernel at N = 2^20 for a described ``v5e:2x2`` and assert that
+Mosaic accepted it (``tpu_custom_call`` in the compiled HLO): interpret
+mode cannot show a tiling or lowering error, this can. One hash-exchange
+stage is compiled over the four described devices, to check its
+``all-to-all``, and the one-chip sort stage is compiled to check that its
+payload rides in the sort (about 30 s here).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -24,9 +24,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from repro.core import shuffle as sh
 from repro.core.context import IContext
-from repro.kernels.moe_route.ops import bucket_route
 from repro.kernels.segment_reduce.ops import segment_totals
-from repro.kernels.ssd_scan.ops import prefix_scan
 
 N = 1 << 20
 BLOCK = 8192
@@ -79,20 +77,6 @@ def test_segment_totals_compiles_for_v5e(one_chip, op, dtype):
     _assert_kernel(compiled)
 
 
-def test_reverse_prefix_min_compiles_for_v5e(one_chip):
-    def f(x):
-        return prefix_scan(x, op="min", block=BLOCK, interpret=False, reverse=True)
-
-    _assert_kernel(jax.jit(f).lower(_arg((N,), jnp.int32, one_chip)).compile())
-
-
-def test_bucket_route_compiles_for_v5e(one_chip):
-    def f(dest):
-        return bucket_route(dest, 4, N // 2, block=BLOCK, interpret=False)
-
-    _assert_kernel(jax.jit(f).lower(_arg((N,), jnp.int32, one_chip)).compile())
-
-
 def test_oracle_segmented_reduce_compiles_for_v5e(one_chip):
     # the plain-JAX fallback of the segment kernel (ignis.kernels=off): a
     # 2^20-row associative_scan took minutes and GiBs to compile for v5e,
@@ -126,12 +110,10 @@ def test_hash_stage_compiles_on_four_v5e_chips(topo):
     ctx = IContext(mesh, "data")
     p = ctx.executors
     C = sh.capacity_for(2.0, N // p, p)
-    route = sh.make_bucket_route(p, C, BLOCK, interpret=False)
     rows = NamedSharding(mesh, P("data"))
 
     def f(keys, valid, values):
-        return sh.hash_stage(ctx, keys, valid, {"key": keys, "value": values}, C,
-                             route=route)
+        return sh.hash_stage(ctx, keys, valid, {"key": keys, "value": values}, C)
 
     compiled = jax.jit(f).lower(
         _arg((N,), jnp.int32, rows), _arg((N,), jnp.bool_, rows),
@@ -139,7 +121,6 @@ def test_hash_stage_compiles_on_four_v5e_chips(topo):
     text = compiled.as_text()
     assert p == 4
     assert "all-to-all" in text
-    assert "tpu_custom_call" in text
     # every leaf, the bool validity included, crosses 32 bits wide: a bool
     # all-to-all of 2^25 rows per chip takes about a minute to compile
     a2a = re.findall(r"= (\w+)\[[^\]]*\]\S* all-to-all\(", text)
