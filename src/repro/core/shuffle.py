@@ -122,18 +122,21 @@ def _layout(ks, leaves):
     return slot, riders, gathered
 
 
-def sort_traffic(keys, data) -> dict:
-    """What each local sort of ``sort_stage`` compares and moves when it
-    sorts ``data`` by ``keys`` (arrays or tracers, one row each): the key
-    leaves, the leaves gathered after the sort, and the bytes a row of the
-    keys and the data (each distinct array once) and of those gathered
-    leaves."""
+def sort_traffic(keys, data, p: int) -> dict:
+    """What ``sort_stage`` on p executors compares and moves when it sorts
+    ``data`` by ``keys`` (arrays or tracers, one row each): the key leaves,
+    the leaves gathered after the local sort, the bytes a row of the keys
+    and the data (each distinct array once) and of those gathered leaves,
+    and at p > 1 the bucket slices that fill the send buffers (p for each
+    distinct leaf the local sort returns, ``valid`` included)."""
     ks = key_leaves(keys)
     _, riders, gathered = _layout(ks, jax.tree.leaves(data))
     moved = [*_distinct(ks)[0], *riders, *gathered]
+    sent = 1 + len(ks) + len(riders) + len(gathered)
     return dict(key_leaves=len(ks), gathers=len(gathered),
                 row_bytes=sum(map(row_bytes, moved)),
-                gather_row_bytes=sum(map(row_bytes, gathered)))
+                gather_row_bytes=sum(map(row_bytes, gathered)),
+                bucket_slices=p * sent if p > 1 else 0)
 
 
 def _sort_carry(keys, valid, *trees):
@@ -250,32 +253,47 @@ def _pack_exchange(dest, payload, axis, p, C):
         return _exchange(packed, axis, p, C), overflow, max_fill
 
 
+def _fill_sorted(dest, payload, end, p, C):
+    """``_pack`` for rows already in destination order: PSRS rows are
+    sorted by key, so ``dest`` is non-decreasing and bucket d is the run of
+    rows ``[starts[d], starts[d] + counts[d])``. The rows from ``end`` on
+    (the invalid tail) are not sent and take no capacity: after a wide
+    stage, padding fills half of a block, and routed to the last executor
+    it overflowed that bucket.
+
+    Each bucket is one contiguous window of C rows, masked past its count:
+    p slices of each distinct leaf, padded by C rows so that no window
+    starting near the end is clamped back. No gather, no second argsort
+    and no scatter (the last two are each a sort on XLA:TPU). Returns
+    (pytree of (p·C, …), overflow, max_fill), bit-identical to ``_pack``
+    on the rows it keeps and zero in every other slot."""
+    bounds = jnp.searchsorted(dest, jnp.arange(p + 1, dtype=dest.dtype), side="left")
+    starts = bounds[:p].astype(jnp.int32)
+    ends = jnp.minimum(bounds[1:].astype(jnp.int32), end)
+    counts = jnp.maximum(ends - starts, 0)
+    overflow = jnp.maximum(counts - C, 0).sum().astype(jnp.int32)
+    max_fill = counts.max().astype(jnp.int32)
+    keep = jnp.arange(C, dtype=jnp.int32) < counts[:, None]  # (p, C)
+
+    def fill(x):
+        x = jnp.pad(x, [(0, C)] + [(0, 0)] * (x.ndim - 1))
+        rows = jnp.stack([jax.lax.dynamic_slice_in_dim(x, starts[d], C)
+                          for d in range(p)])
+        m = keep.reshape(keep.shape + (1,) * (x.ndim - 1))
+        return jnp.where(m, rows, jnp.zeros((), x.dtype)).reshape(p * C, *x.shape[1:])
+
+    leaves, treedef = jax.tree.flatten(payload)
+    uniq, index = _distinct(leaves)
+    filled = [fill(x) for x in uniq]
+    return jax.tree.unflatten(treedef, [filled[i] for i in index]), overflow, max_fill
+
+
 def _pack_sorted(dest, payload, end, axis, p, C):
-    """``_pack_exchange`` for rows already in destination order: PSRS rows
-    are sorted by key, so ``dest`` is non-decreasing and bucket d is one run
-    of rows. The rows from ``end`` on (the invalid tail) are not sent and
-    take no capacity: after a wide stage, padding fills half of a block,
-    and routed to the last executor it overflowed that bucket. A gather
-    fills the buckets, so the stage carries no second argsort and no
-    scatter (each a sort on XLA:TPU). Bit-identical to ``_pack_exchange``
-    on the rows it keeps."""
+    """``_pack_exchange`` for rows already in destination order: the
+    buckets filled by ``_fill_sorted``, then exchanged."""
     with _scope(EXCHANGE):
-        bounds = jnp.searchsorted(dest, jnp.arange(p + 1, dtype=dest.dtype), side="left")
-        starts = bounds[:p].astype(jnp.int32)
-        ends = jnp.minimum(bounds[1:].astype(jnp.int32), end)
-        counts = jnp.maximum(ends - starts, 0)
-        overflow = jnp.maximum(counts - C, 0).sum().astype(jnp.int32)
-        max_fill = counts.max().astype(jnp.int32)
-        slot = jnp.arange(p * C, dtype=jnp.int32)
-        d, i = slot // C, slot % C
-        keep = i < counts[d]
-        src = jnp.where(keep, starts[d] + i, 0)
-
-        def pack(x):
-            m = keep.reshape((-1,) + (1,) * (x.ndim - 1))
-            return jnp.where(m, x[src], jnp.zeros((), x.dtype))
-
-        return _exchange(jax.tree.map(pack, payload), axis, p, C), overflow, max_fill
+        packed, overflow, max_fill = _fill_sorted(dest, payload, end, p, C)
+        return _exchange(packed, axis, p, C), overflow, max_fill
 
 
 def _exchange(packed, axis, p, C):

@@ -200,6 +200,7 @@ class ShuffleManager:
             "wide_plan_misses": 0,     # wide-stage compiles
             "wide_plan_evictions": 0,
             "sort_gathers": 0,         # leaves gathered after a sort, per plan built
+            "bucket_slices": 0,        # PSRS send-bucket slices, per plan built
             "sort_key_leaves": 0,      # key leaves a sort compares, per plan built
             "sort_bytes": 0,           # key and data bytes through the local sorts
             "sort_gather_bytes": 0,    # of those, bytes gathered after a sort
@@ -428,9 +429,10 @@ class ShuffleManager:
                 if not ascending:
                     keys = jax.tree.map(_descending, keys)
                 # filled once, when the plan is traced
-                traffic.update(sh.sort_traffic(keys, data))
+                traffic.update(sh.sort_traffic(keys, data, ctx.executors))
                 self._bump("sort_key_leaves", traffic["key_leaves"])
                 self._bump("sort_gathers", traffic["gathers"])
+                self._bump("bucket_slices", traffic["bucket_slices"])
                 return sh.sort_stage(ctx, keys, valid, data, C, post)
 
             return run, traffic
@@ -602,7 +604,8 @@ class ShuffleManager:
             f"capacity_memory: hits={s['capacity_memory_hits']} "
             f"misses={s['capacity_memory_misses']} entries={len(self._capacity)}\n"
             f"wide plans: compiled={s['wide_plan_misses']} hits={s['wide_plan_hits']} "
-            f"sort_gathers={s['sort_gathers']} evictions={s['wide_plan_evictions']} "
+            f"sort_gathers={s['sort_gathers']} bucket_slices={s['bucket_slices']} "
+            f"evictions={s['wide_plan_evictions']} "
             f"bytes_moved={s['bytes_moved']} group_reshards={s['group_reshards']}\n"
             f"sorts: key_leaves={s['sort_key_leaves']} bytes={s['sort_bytes']} "
             f"gather_bytes={s['sort_gather_bytes']}\n"

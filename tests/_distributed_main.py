@@ -48,20 +48,23 @@ def main():
     ints[rng.random(n) < 0.05] = np.iinfo(np.int32).max
     valid[:8] = False
     cases = {
-        "int32_desc": (jnp.asarray(ints), lambda r: r, False, 0),
-        "float32_asc": (jnp.asarray((ints / 4).astype(np.float32)), lambda r: r, True, 0),
+        "int32_desc": (jnp.asarray(ints), lambda r: r, False, 0, 3),
+        "float32_asc": (jnp.asarray((ints / 4).astype(np.float32)), lambda r: r, True, 0, 2),
         "tree_2d_leaf": ({"key": jnp.asarray(ints % 7),
                           "vec": jnp.asarray(rng.standard_normal((n, 3)), jnp.float32)},
-                         lambda r: r["key"], True, 1),
-        "max_key_after_invalid": (jnp.asarray(ints), lambda r: r, True, 0),
+                         lambda r: r["key"], True, 1, 3),
+        "max_key_after_invalid": (jnp.asarray(ints), lambda r: r, True, 0, 2),
     }
-    for name, (data, key_fn, ascending, gathers) in cases.items():
+    # the last number: leaves the PSRS exchange sends, each filled by 8
+    # bucket slices (valid, the key, and every data leaf that is not it)
+    for name, (data, key_fn, ascending, gathers, sent) in cases.items():
         keys = jax.vmap(key_fn)(data)
         keys = keys if ascending else -keys
         order = jnp.argsort(jnp.where(valid, keys, sh._sentinel(keys.dtype)), stable=True)
         keep = np.asarray(valid)[np.asarray(order)]
         want = jax.tree.map(lambda x: np.asarray(x[order])[keep], data)
         before = w.shuffle.stats["sort_gathers"]
+        slices = w.shuffle.stats["bucket_slices"]
         out = w.shuffle.sort(("carry", name), Block(data, jnp.asarray(valid)),
                              key_fn, ascending)
         got_v = np.asarray(out.valid)
@@ -69,7 +72,8 @@ def main():
         check(f"sort_carry_8shards_{name}",
               all(g.tobytes() == x.tobytes() for g, x in
                   zip(jax.tree.leaves(got_d), jax.tree.leaves(want)))
-              and w.shuffle.stats["sort_gathers"] - before == gathers)
+              and w.shuffle.stats["sort_gathers"] - before == gathers
+              and w.shuffle.stats["bucket_slices"] - slices == 8 * sent)
 
     # tuple keys on 8 shards (PSRS samples, pivots and routing compared
     # lexicographically) against the NumPy reference of the CPU tests: ties

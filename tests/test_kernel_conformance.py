@@ -217,6 +217,44 @@ def test_pack_matches_stable_argsort(n, p, capacity):
     _check_pack(rng.integers(0, p, n).astype(np.int32), p, capacity)
 
 
+def _fill_leaf(kind, n, rng):
+    if kind == "int32":
+        return jnp.asarray(rng.integers(-2**31, 2**31, n), jnp.int32)
+    if kind == "uint32x23":
+        return jnp.asarray(rng.integers(0, 2**32, (n, 23)), jnp.uint32)
+    assert kind == "bool"
+    return jnp.asarray(rng.random(n) < 0.5)
+
+
+@pytest.mark.parametrize("counts,tail,capacity,kind", [
+    ((30, 30, 30, 10), 0, 40, "int32"),    # bucket 3 starts at 90 > n - C = 60
+    ((5, 9, 3, 7), 24, 12, "int32"),       # invalid tail: end < n
+    ((20, 3, 17), 4, 8, "int32"),          # overflow: counts > C
+    ((6, 0, 10), 0, 16, "int32"),          # C = n
+    ((0, 7, 0, 5, 0), 3, 6, "int32"),      # empty buckets, first and last
+    ((9, 4, 11, 6), 5, 10, "uint32x23"),   # a leaf with trailing dimensions
+    ((9, 4, 11, 6), 5, 10, "bool"),        # a bool leaf
+])
+def test_fill_sorted_matches_stable_argsort(counts, tail, capacity, kind):
+    # the PSRS send buckets filled by slices of destination-sorted rows vs
+    # the hash exchange's reference: each kept row in its slot, zero in
+    # every other, and the rows from ``end`` on neither sent nor counted
+    p, end = len(counts), sum(counts)
+    dest = np.repeat(np.arange(p + 1, dtype=np.int32), [*counts, tail])
+    n = dest.shape[0]
+    rng = np.random.default_rng(n + p)
+    x = _fill_leaf(kind, n, rng)
+    rows = {"row": jnp.arange(n, dtype=jnp.int32), "x": x}
+    packed, overflow, max_fill = sh._fill_sorted(
+        jnp.asarray(dest), rows, jnp.int32(end), p, capacity)
+    slots, ovf, fill = _pack_ref(dest[:end], p, capacity)
+    kept = (slots >= 0).reshape((-1,) + (1,) * (x.ndim - 1))
+    want = np.where(kept, np.asarray(x)[np.maximum(slots, 0)], np.zeros((), x.dtype))
+    assert bits_equal(packed["x"], want)
+    assert bits_equal(packed["row"], np.where(slots >= 0, slots, 0).astype(np.int32))
+    assert int(overflow) == ovf and int(max_fill) == fill
+
+
 def test_pack_all_one_destination():
     dest = np.zeros(90, np.int32)
     _check_pack(dest, 4, 100)

@@ -3,8 +3,9 @@ ops and dtypes against the pure-jnp oracles, plus the end-to-end
 reduceByKey path with the kernel tier forced on vs a Python oracle
 (docs/kernels.md — bit-identity is the contract for associative-exact
 data, so every comparison here is exact, never a tolerance)."""
-import numpy as np
+import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 pytest.importorskip("hypothesis", reason="dev-only dependency (requirements-dev.txt)")
@@ -90,6 +91,54 @@ def test_pack_random(p, capacity, dest):
     assert np.array_equal(got, want)
     assert int(overflow) == n - int(np.minimum(counts, capacity).sum())
     assert int(max_fill) == int(counts.max())
+
+
+def _gather_fill(dest, payload, end, p, C):
+    """The PSRS send buckets filled by a gather over the p·C slots, as the
+    sort stage filled them before it took slices."""
+    bounds = jnp.searchsorted(dest, jnp.arange(p + 1, dtype=dest.dtype), side="left")
+    starts = bounds[:p].astype(jnp.int32)
+    counts = jnp.maximum(jnp.minimum(bounds[1:].astype(jnp.int32), end) - starts, 0)
+    slot = jnp.arange(p * C, dtype=jnp.int32)
+    d, i = slot // C, slot % C
+    keep = i < counts[d]
+    src = jnp.where(keep, starts[d] + i, 0)
+
+    def pack(x):
+        m = keep.reshape((-1,) + (1,) * (x.ndim - 1))
+        return jnp.where(m, x[src], jnp.zeros((), x.dtype))
+
+    return (jax.tree.map(pack, payload), keep,
+            jnp.maximum(counts - C, 0).sum().astype(jnp.int32),
+            counts.max().astype(jnp.int32))
+
+
+@given(st.integers(2, 8), st.integers(1, 64),
+       st.lists(st.integers(0, 7), min_size=1, max_size=300),
+       st.integers(0, 40), st.integers(0, 2**32 - 1))
+@_settings
+def test_fill_sorted_random(p, capacity, dest, tail, seed):
+    # the PSRS fill by slices vs the gather it replaced, on sorted
+    # destinations with an invalid tail: every slot bit for bit (a masked
+    # slot is zero, even where the leaf holds -0.0 or NaN bits), and the
+    # overflow and largest bucket demand equal
+    d = np.sort(np.asarray([x % p for x in dest], np.int32))
+    end = d.shape[0]
+    d = np.concatenate([d, np.full(tail, p, np.int32)])
+    n = d.shape[0]
+    rng = np.random.default_rng(seed)
+    bits = rng.integers(0, 2**32, (n, 2), dtype=np.uint64).astype(np.uint32)
+    rows = {"f": jnp.asarray(bits.view(np.float32)),
+            "row": jnp.arange(n, dtype=jnp.int32),
+            "valid": jnp.asarray(np.arange(n) < end)}
+    args = (jnp.asarray(d), rows, jnp.int32(end), p, capacity)
+    got, overflow, max_fill = sh._fill_sorted(*args)
+    want, keep, w_overflow, w_max_fill = _gather_fill(*args)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert bits_equal(g, w)
+    masked = np.asarray(got["f"]).view(np.uint32)[~np.asarray(keep)]
+    assert not masked.any()
+    assert int(overflow) == int(w_overflow) and int(max_fill) == int(w_max_fill)
 
 
 @given(st.lists(st.integers(0, 2**15 - 1), min_size=1, max_size=60),

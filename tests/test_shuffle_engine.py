@@ -288,6 +288,10 @@ def test_sort_counters_count_key_leaves_and_bytes(worker):
     assert d["sort_key_leaves"] == 3 and d["sort_gathers"] == 1
     assert d["sort_bytes"] == 2 * 64 * row
     assert d["sort_gather_bytes"] == 2 * 64 * 20
+    # one executor sends nothing; on four, p slices of each of the six
+    # leaves the local sort returns (valid, a, b, c, row, pay)
+    assert d["bucket_slices"] == 0
+    assert sh.sort_traffic(TUPLE_KEYS[3](b.data), b.data, 4)["bucket_slices"] == 4 * 6
     # descending: the keys are new arrays, each an operand beside its leaf
     worker.shuffle.sort(("count", 1), b, lambda r: r["a"], False)
     assert worker.shuffle.stats["sort_key_leaves"] - before["sort_key_leaves"] == 4

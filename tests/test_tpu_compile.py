@@ -105,6 +105,26 @@ def test_sort_stage_carries_payload_in_one_sort_for_v5e(topo, one_chip):
     assert " gather(" not in text
 
 
+def test_psrs_sort_stage_fills_buckets_without_gather_on_four_v5e_chips(topo):
+    # the four-device sort stage of one int32 key: the send buckets are
+    # filled by slices of the sorted rows, so no gather has a p·C-row
+    # output (one per leaf filled its buckets before)
+    mesh = Mesh(np.asarray(topo.devices), ("data",))
+    ctx = IContext(mesh, "data")
+    p = ctx.executors
+    C = sh.capacity_for(2.0, N // p, p)
+    rows = NamedSharding(mesh, P("data"))
+
+    def f(keys, valid):
+        return sh.sort_stage(ctx, keys, valid, keys, C)
+
+    text = jax.jit(f).lower(_arg((N,), jnp.int32, rows),
+                            _arg((N,), jnp.bool_, rows)).compile().as_text()
+    assert p == 4 and "all-to-all" in text
+    gathers = re.findall(r"= \w+\[(\d+)[^\]]*\]\S* gather\(", text)
+    assert str(p * C) not in gathers, gathers
+
+
 def test_hash_stage_compiles_on_four_v5e_chips(topo):
     mesh = Mesh(np.asarray(topo.devices), ("data",))
     ctx = IContext(mesh, "data")
